@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from benchmarks.common import timed
 from repro.kernels import ref
-from repro.kernels.block_sort import bitonic_sort
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.index_search import index_search
 from repro.kernels.pax_scan import pax_scan
@@ -70,11 +69,6 @@ def reader_dispatch_stats(n_queries: int = 10) -> dict:
 
 def run():
     rows = []
-    keys = jax.random.randint(KEY, (4, 1024), 0, 1 << 20, dtype=jnp.int32)
-    t, _ = timed(lambda: bitonic_sort(keys))
-    tr, _ = timed(lambda: jax.vmap(ref.sort_by_key)(keys))
-    rows.append(("kernel_block_sort_4x1024", t * 1e6, f"ref_us={tr * 1e6:.0f}"))
-
     mins = jnp.sort(jax.random.randint(KEY, (64, 64), 0, 1 << 20,
                                        dtype=jnp.int32), axis=1)
     t, _ = timed(lambda: index_search(mins, 1000, 100000))

@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on module name")
     args = ap.parse_args()
+    from repro.compile_cache import place_compile_cache
+    place_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     for mod_name in MODULES:

@@ -64,11 +64,12 @@ def search_range(mins: jax.Array, lo, hi, partition_size: int,
                  n_rows: int) -> tuple[jax.Array, jax.Array]:
     """-> (row_start, row_end) half-open row range covering [lo, hi].
 
-    p_first = last partition whose min <= lo (clamped to 0);
+    p_first = last partition whose min < lo (clamped to 0) — a key equal
+              to lo may end the partition before the first whose min is lo;
     p_last  = last partition whose min <= hi.
     """
     p_first = jnp.maximum(
-        jnp.searchsorted(mins, lo, side="right").astype(jnp.int32) - 1, 0)
+        jnp.searchsorted(mins, lo, side="left").astype(jnp.int32) - 1, 0)
     p_last = jnp.maximum(
         jnp.searchsorted(mins, hi, side="right").astype(jnp.int32) - 1, 0)
     row_start = p_first * partition_size

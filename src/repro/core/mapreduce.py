@@ -17,8 +17,8 @@ Two executors:
   ``adaptive=AdaptiveConfig(...)`` enables LAZY ADAPTIVE INDEXING ("Towards
   Zero-Overhead Adaptive Indexing in Hadoop"): full-scan splits additionally
   sort + index an offered fraction of their still-unindexed blocks — the
-  bitonic ``kernels/block_sort`` does the in-kernel sort, the clustered root
-  directory comes from ``core/index`` — and commit the result back into the
+  same stable sort as the eager upload (``ops.sort_block``), the clustered
+  root directory from ``core/index`` — and commit the result back into the
   ``BlockStore`` mid-job, so repeated jobs over the same store converge from
   all-full-scan to all-index-scan with no eager upload cost.
 
@@ -108,9 +108,9 @@ class AdaptiveConfig:
 def _build_block_indexes(store: BlockStore, replica_id: int, block_ids,
                          key: str, *, partition_size: int) -> int:
     """Sort + index + commit ``block_ids`` of one replica by ``key``, as one
-    batched dispatch per call (the ``kernels/block_sort`` bitonic network
-    when rows is a power of two).  Bad records are forced to the tail with
-    the INT32_MAX sentinel, exactly like the eager upload sort."""
+    batched stable sort per call (``ops.sort_block``).  Bad records are
+    forced to the tail with the INT32_MAX sentinel, exactly like the eager
+    upload sort."""
     from repro.kernels import ops
 
     rep = store.replicas[replica_id]
@@ -584,11 +584,8 @@ def spmd_aggregate(mesh, key_col: jax.Array, val_col: jax.Array,
                    mask: jax.Array, n_buckets: int, axis: str = "data"):
     """GROUP-BY-sum: (blocks, rows) keys/values/mask sharded on `axis` ->
     (n_buckets,) sums + counts.  n_buckets must divide by mesh[axis]."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:  # jax >= 0.6 re-exports shard_map at the top level
-        from jax import shard_map
-    except ImportError:  # pinned 0.4.x: experimental home
-        from jax.experimental.shard_map import shard_map
 
     n_dev = mesh.shape[axis]
     if n_dev <= 0 or n_buckets % n_dev != 0:

@@ -335,7 +335,7 @@ def _gather_replica_inputs(store: BlockStore, rid: int, bsel: np.ndarray,
                         args={"replica": rid, "blocks": len(bsel)}):
         _verify_replica_blocks(store, rid, bsel, (col,) + proj_cols)
         val = (rep.cols[col][bsel],
-               jnp.stack([rep.cols[c][bsel] for c in proj_cols], axis=-1),
+               jnp.stack([rep.cols[c][bsel] for c in proj_cols], axis=1),
                _bad_mask(store, rid)[bsel],
                rep.mins[bsel])
     if cache is not None:
@@ -441,7 +441,7 @@ def read_hail_kernels(store: BlockStore, query: HailQuery, qplan: QueryPlan,
     mask, out, frac = ops.hail_read(mins, keys, proj, bad, uidx,
                                     lo, hi,
                                     partition_size=store.partition_size)
-    cols = {c: out[..., j] for j, c in enumerate(proj_cols)}
+    cols = {c: out[:, j] for j, c in enumerate(proj_cols)}
     col_bytes = 4 * rows
     return ReadResult(cols=cols, mask=mask, rows_read_frac=frac,
                       bytes_read=frac.sum() * col_bytes
@@ -489,9 +489,9 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
     mask, out, frac = ops.hail_read_batch(mins, keys, proj_arr, bad, uidx,
                                           lohi,
                                           partition_size=store.partition_size)
-    cols = {c: out[..., j] for j, c in enumerate(proj_cols)}
+    cols = {c: out[:, j] for j, c in enumerate(proj_cols)}
     results = [
-        ReadResult(cols=cols, mask=mask[..., qi],
+        ReadResult(cols=cols, mask=mask[:, qi],
                    rows_read_frac=frac[:, qi],
                    bytes_read=frac[:, qi].sum() * col_bytes
                    * (1 + len(proj)))
@@ -602,10 +602,10 @@ def read_hail_batch_sharded(store: BlockStore,
     outs = []
     for s in range(n_splits):
         sl = slice(s * bmax, s * bmax + sizes[s])
-        cols = {c: out[sl, :, j] for j, c in enumerate(proj_cols)}
+        cols = {c: out[sl, j] for j, c in enumerate(proj_cols)}
         m, fr = mask[sl], frac[sl]
         results = [
-            ReadResult(cols=cols, mask=m[..., qi],
+            ReadResult(cols=cols, mask=m[:, qi],
                        rows_read_frac=fr[:, qi],
                        bytes_read=fr[:, qi].sum() * col_bytes
                        * (1 + len(proj)))

@@ -521,9 +521,9 @@ class BlockStore:
         if len(bsel):                           # upload order (mid-re-key)
             # device-side un-sort: sorting by the logical __rowid__ column
             # IS the inverse permutation back to upload order, and it runs
-            # through the same kernels/block_sort bitonic network the build
-            # path uses — so the rekey_s wall charged to demotions is honest
-            # on TPU, not a host argsort artifact (ROADMAP item).
+            # through the same stable device sort the build path uses — so
+            # the rekey_s wall charged to demotions is a device wall, not a
+            # host argsort artifact.
             from repro.kernels import ops
             _, unsorted, _ = ops.sort_block(
                 rep.cols[ROWID][bsel],

@@ -9,12 +9,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6
-    from jax import shard_map
-except ImportError:  # pinned 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 
 def init_residual(grads):

@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the HAIL hot path (``ops`` wraps them, ``ref`` holds
+their pure-jnp oracles)."""
+import jax
+
+
+def interpret_default() -> bool:
+    """Pallas kernels run in the interpreter exactly where JAX's default
+    backend is the CPU; on a TPU they lower through Mosaic."""
+    return jax.default_backend() == "cpu"

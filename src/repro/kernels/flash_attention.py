@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 NEG_INF = -1e30
 
 
@@ -65,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q (B,T,H,D), k/v (B,S,KV,D), H = KV*rep -> (B,T,H,D)."""
     b, t, h, d = q.shape
     s, kvh = k.shape[1], k.shape[2]
@@ -104,6 +106,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denominator
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(qr, kr, vr)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)

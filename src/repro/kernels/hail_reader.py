@@ -8,18 +8,16 @@ re-created the exact per-task overhead the paper kills (3,200 map tasks ->
 ~20 splits, Fig 6c): every block paid a kernel dispatch, and every new
 query range paid a recompile because (lo, hi) were baked in as Python ints.
 
-Here the whole split is a single ``pallas_call`` with a 2D grid over
-``(block, row_tile)``:
+Here the whole split is a single jitted program: the root-directory lookup
+(a popcount over the partition minima per block and query) and a single
+``pallas_call`` with a 2D grid over ``(block, row_tile)``:
 
-* the per-block ROOT DIRECTORY (partition minima) rides along in VMEM; each
-  grid step recomputes the block's qualifying partition range with the same
-  popcount-of-(mins <= v) reduction ``index_search`` used — a VPU reduction
-  is far cheaper than a second dispatch;
-* the query ranges live in SMEM as a RUNTIME ``(Q, 2)`` lo/hi array, so one
-  compiled reader serves every query — and every BATCH of Q concurrent
-  queries — against the same store shape, with zero per-query recompiles.
-  Q is static (it shapes the mask output), so a server batching at a fixed
-  ``max_batch`` compiles one extra variant per distinct batch size, once;
+* the query ranges and each (block, query)'s qualifying row range are
+  RUNTIME scalar-prefetch arrays in SMEM, so one compiled reader serves
+  every query — and every BATCH of Q concurrent queries — against the same
+  store shape, with zero per-query recompiles.  Q is static (it shapes the
+  mask output), so a server batching at a fixed ``max_batch`` compiles one
+  extra variant per distinct batch size, once;
 * each grid step evaluates ALL Q range predicates against the one key tile
   it already loaded — the shared-scan win: Q concurrent range queries over
   a split cost one dispatch and one pass over the data instead of Q;
@@ -34,6 +32,13 @@ Here the whole split is a single ``pallas_call`` with a 2D grid over
   masked by the UNION of the query masks (rows no query wants stay zero;
   each query recovers its own rows via its mask), and per-(block, query)
   rows-read fractions feeding the I/O cost model.
+
+Layout (Mosaic tiles the last two dims of every block in (8, 128) units):
+a block's R rows are viewed lane-dense as (R/128, 128) — a free reshape of
+the row-major column — so a row tile is a (sublanes, 128) slab.  The
+projection is column-major, (B, C, R), and the per-query mask is emitted as
+int8 (B, Q, R), so neither puts a small C or Q in the lane dim.  Blocks whose
+row count is not a multiple of 128 are viewed as one (1, R) slab.
 """
 from __future__ import annotations
 
@@ -44,130 +49,149 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 
-def _reader_kernel(lohi_ref, mins_ref, keys_ref, proj_ref, bad_ref, uidx_ref,
-                   mask_ref, out_ref, frac_ref, *,
-                   partition_size: int, rows: int, row_tile: int, n_q: int):
-    t = pl.program_id(1)
 
-    # --- fused index_search: root-directory lookup for THIS block, once per
-    # query (n_q is static — the loop unrolls into n_q VPU reductions) ------
-    mins = mins_ref[...]                                     # (1, P)
-    use_index = uidx_ref[0] > 0
-    tile_lo = t * row_tile
-    r0s, r1s, lives = [], [], []
+def _reader_kernel(lohi_ref, rng_ref, keys_ref, proj_ref, bad_ref,
+                   mask_ref, out_ref, *, n_q: int, tile_sub: int, lanes: int):
+    b = pl.program_id(0)
+    tile_rows = tile_sub * lanes
+    tile_lo = pl.program_id(1) * tile_rows
+
+    r0s, r1s = [], []
+    live_any = False
     for qi in range(n_q):
-        lo = lohi_ref[qi, 0]
-        hi = lohi_ref[qi, 1]
-        p_first = jnp.maximum(jnp.sum(mins <= lo).astype(jnp.int32) - 1, 0)
-        p_last = jnp.maximum(jnp.sum(mins <= hi).astype(jnp.int32) - 1, 0)
-        r0 = jnp.where(use_index, p_first * partition_size, 0)
-        r1 = jnp.where(use_index,
-                       jnp.minimum((p_last + 1) * partition_size, rows), rows)
+        r0 = rng_ref[(b * n_q + qi) * 2]
+        r1 = rng_ref[(b * n_q + qi) * 2 + 1]
         r0s.append(r0)
         r1s.append(r1)
-        lives.append((tile_lo < r1) & (tile_lo + row_tile > r0))
-
-    # --- per-(block, query) rows-read fraction (once, at the first tile) ---
-    @pl.when(t == 0)
-    def _():
-        for qi in range(n_q):
-            frac_ref[0, qi] = (r1s[qi] - r0s[qi]).astype(jnp.float32) / rows
+        live_any = live_any | ((tile_lo < r1) & (tile_lo + tile_rows > r0))
 
     # --- row-tile scan, pruned when the tile is dead for EVERY query -------
-    live_any = lives[0]
-    for lv in lives[1:]:
-        live_any = live_any | lv
-
     @pl.when(live_any)
     def _():
-        keys = keys_ref[0, :]                                # (TR,)
-        r = tile_lo + jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1),
-                                               0)[:, 0]
-        good = ~bad_ref[0, :]
-        any_m = jnp.zeros((row_tile,), jnp.bool_)
+        keys = keys_ref[...]                                 # (TS, L)
+        good = ~bad_ref[...]
+        r = (tile_lo
+             + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0) * lanes
+             + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1))
+        any_m = jnp.zeros(keys.shape, jnp.bool_)
         for qi in range(n_q):
-            lo = lohi_ref[qi, 0]
-            hi = lohi_ref[qi, 1]
-            in_range = (r >= r0s[qi]) & (r < r1s[qi])
-            m = (keys >= lo) & (keys <= hi) & in_range & good
-            mask_ref[0, :, qi] = m
+            m = ((keys >= lohi_ref[2 * qi]) & (keys <= lohi_ref[2 * qi + 1])
+                 & (r >= r0s[qi]) & (r < r1s[qi]) & good)
+            mask_ref[qi] = m.astype(mask_ref.dtype)
             any_m = any_m | m
-        out_ref[0, :, :] = jnp.where(any_m[:, None], proj_ref[0, :, :], 0)
+        out_ref[...] = jnp.where(any_m[None], proj_ref[...], 0)
 
     @pl.when(~live_any)                                      # pruned tile
     def _():
-        mask_ref[0, :, :] = jnp.zeros((row_tile, n_q), jnp.bool_)
-        out_ref[0, :, :] = jnp.zeros_like(out_ref[0, :, :])
+        mask_ref[...] = jnp.zeros(mask_ref.shape, mask_ref.dtype)
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+
+def row_ranges(mins, use_index, lohi, *, partition_size: int, rows: int):
+    """Root-directory lookup for every (block, query): the half-open row
+    range [r0, r1) an index scan must read — the whole block where
+    ``use_index`` is 0.  The first partition read is the last whose min is
+    < lo (keys equal to lo may end the partition before one whose min is
+    lo), the last is the last whose min is <= hi.  mins (B, P), use_index
+    (B,), lohi (Q, 2) -> r0, r1 (B, Q) int32."""
+    def count(v, below):                                     # (Q,) -> (B, Q)
+        m = mins[:, None, :]
+        v = v[None, :, None]
+        return jnp.sum(m < v if below else m <= v, axis=-1, dtype=jnp.int32)
+
+    use = use_index[:, None] > 0
+    p_first = jnp.maximum(count(lohi[:, 0], True) - 1, 0)
+    p_last = jnp.maximum(count(lohi[:, 1], False) - 1, 0)
+    r0 = jnp.where(use, p_first * partition_size, 0)
+    r1 = jnp.where(use, jnp.minimum((p_last + 1) * partition_size, rows),
+                   rows)
+    return r0.astype(jnp.int32), r1.astype(jnp.int32)
+
+
+def _tile_sublanes(sub: int, want: int) -> int:
+    """Largest divisor of ``sub`` that is <= ``want`` and a multiple of 32
+    (the int8 mask's sublane tile); the whole ``sub`` when it fits."""
+    if sub <= want:
+        return sub
+    for ts in range(want - want % 32, 0, -32):
+        if sub % ts == 0:
+            return ts
+    return sub
 
 
 def hail_read_batch(mins: jax.Array, keys: jax.Array, proj: jax.Array,
                     bad: jax.Array, use_index: jax.Array, lohi: jax.Array, *,
-                    partition_size: int, row_tile: int = 1024,
-                    interpret: bool = True):
-    """Fused shared-scan reader — one pallas_call for all blocks of a split
+                    partition_size: int, interpret: bool,
+                    row_tile: int = 32768):
+    """Fused shared-scan reader — one program for all blocks of a split
     AND all Q queries of a batch.
 
     mins (B, P) int32       per-block root directories (ignored where
                             ``use_index`` is 0)
     keys (B, R) int32       filter column, replica-chosen per block
-    proj (B, R, C)          projection columns (+rowid), same replicas
+    proj (B, C, R)          projection columns (+rowid), same replicas
     bad  (B, R) bool        bad-record positions per block
     use_index (B,) int32    1 = clustered index matches -> partition pruning
-    lohi (Q, 2) int32       RUNTIME per-query (lo, hi) ranges in SMEM
+    lohi (Q, 2) int32       RUNTIME per-query (lo, hi) ranges
 
-    -> (mask (B, R, Q) bool — per-query match masks,
-        proj masked by the union of the Q masks (B, R, C),
+    -> (mask (B, Q, R) bool — per-query match masks,
+        proj masked by the union of the Q masks (B, C, R),
         rows_read_frac (B, Q) f32)
     """
     b, rows = keys.shape
-    c = proj.shape[2]
+    c = proj.shape[1]
     n_q = lohi.shape[0]
-    tr = min(row_tile, rows)
-    while rows % tr:
-        tr -= 1
-    n_tiles = rows // tr
-    kernel = functools.partial(_reader_kernel, partition_size=partition_size,
-                               rows=rows, row_tile=tr, n_q=n_q)
-    mask, out, frac = pl.pallas_call(
-        kernel,
-        grid=(b, n_tiles),
+    lanes = LANES if rows % LANES == 0 else rows
+    sub = rows // lanes
+    ts = _tile_sublanes(sub, max(row_tile // lanes, 1))
+    lohi = jnp.asarray(lohi, jnp.int32)
+    r0, r1 = row_ranges(mins, use_index, lohi,
+                        partition_size=partition_size, rows=rows)
+    kernel = functools.partial(_reader_kernel, n_q=n_q, tile_sub=ts,
+                               lanes=lanes)
+    rows_spec = pl.BlockSpec((None, ts, lanes), lambda i, t, *_: (i, t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, sub // ts),
         in_specs=[
-            pl.BlockSpec((n_q, 2), lambda i, t: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, mins.shape[1]), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, tr), lambda i, t: (i, t)),
-            pl.BlockSpec((1, tr, c), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, tr), lambda i, t: (i, t)),
-            pl.BlockSpec((1,), lambda i, t: (i,),
-                         memory_space=pltpu.SMEM),
+            rows_spec,
+            pl.BlockSpec((None, c, ts, lanes), lambda i, t, *_: (i, 0, t, 0)),
+            rows_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, tr, n_q), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, tr, c), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, n_q), lambda i, t: (i, 0)),
+            pl.BlockSpec((None, n_q, ts, lanes),
+                         lambda i, t, *_: (i, 0, t, 0)),
+            pl.BlockSpec((None, c, ts, lanes), lambda i, t, *_: (i, 0, t, 0)),
         ],
+    )
+    mask, out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, rows, n_q), jnp.bool_),
-            jax.ShapeDtypeStruct((b, rows, c), proj.dtype),
-            jax.ShapeDtypeStruct((b, n_q), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_q, sub, lanes), jnp.int8),
+            jax.ShapeDtypeStruct((b, c, sub, lanes), proj.dtype),
         ],
         interpret=interpret,
-    )(jnp.asarray(lohi, jnp.int32), mins, keys, proj, bad,
-      use_index.astype(jnp.int32))
-    return mask, out, frac
+    )(lohi.reshape(-1), jnp.stack([r0, r1], axis=-1).reshape(-1),
+      keys.reshape(b, sub, lanes), proj.reshape(b, c, sub, lanes),
+      bad.reshape(b, sub, lanes))
+    frac = (r1 - r0).astype(jnp.float32) / rows
+    return (mask.reshape(b, n_q, rows).astype(jnp.bool_),
+            out.reshape(b, c, rows), frac)
 
 
 def hail_read(mins: jax.Array, keys: jax.Array, proj: jax.Array,
               bad: jax.Array, use_index: jax.Array, lo, hi, *,
-              partition_size: int, row_tile: int = 1024,
-              interpret: bool = True):
+              partition_size: int, interpret: bool):
     """Single-query fused split reader: the Q=1 case of ``hail_read_batch``.
 
-    -> (mask (B, R) bool, masked proj (B, R, C), rows_read_frac (B,) f32)
+    -> (mask (B, R) bool, masked proj (B, C, R), rows_read_frac (B,) f32)
     """
-    lohi = jnp.asarray([lo, hi], jnp.int32).reshape(1, 2)
+    lohi = jnp.stack([jnp.asarray(lo, jnp.int32),
+                      jnp.asarray(hi, jnp.int32)]).reshape(1, 2)
     mask, out, frac = hail_read_batch(mins, keys, proj, bad, use_index, lohi,
                                       partition_size=partition_size,
-                                      row_tile=row_tile, interpret=interpret)
-    return mask[..., 0], out, frac[:, 0]
+                                      interpret=interpret)
+    return mask[:, 0], out, frac[:, 0]

@@ -1,12 +1,12 @@
-"""jit'd wrappers around the Pallas kernels (+ oracle fallbacks).
+"""jit'd wrappers around the Pallas kernels.
 
-On this CPU container kernels run in interpret mode (correctness); on a
-real TPU export ``REPRO_PALLAS_INTERPRET=0`` (or call ``set_interpret``)
-to lower through Mosaic — no code edit needed.  ``use_kernels(False)``
-routes everything to the pure-jnp oracles in ref.py.  The kernel-backed record reader
-(core.query.read_hail_kernels) calls through these wrappers and is asserted
-equivalent to the jnp reader by the system test suite, so kernel/oracle
-agreement is exercised end-to-end, not only by per-kernel allclose tests.
+Interpret mode follows the platform: kernels run in the Pallas interpreter
+only where JAX's default backend is the CPU, and lower through Mosaic
+everywhere else — no environment knob, no silent fallback.  The
+kernel-backed record readers (core.query.read_hail_kernels /
+read_hail_batch) call through these wrappers and are asserted equivalent
+to the jnp reader by the system test suite, so kernel/oracle agreement is
+exercised end-to-end, not only by per-kernel tests.
 
 Dispatch/recompile accounting: every wrapper that backs the record reader
 bumps ``DISPATCH_COUNTS`` per call and ``TRACE_COUNTS`` per retrace (a
@@ -21,63 +21,40 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import checksum as _ck
-from repro.kernels import ref
-from repro.kernels.block_sort import bitonic_sort
-from repro.kernels.flash_attention import flash_attention
+from repro.core import index as _idx
+from repro.kernels import interpret_default
 from repro.kernels.hail_reader import hail_read as _hail_read
 from repro.kernels.hail_reader import hail_read_batch as _hail_read_batch
-from repro.kernels.index_search import index_search as _index_search
-from repro.kernels.pax_scan import pax_scan as _pax_scan
 from repro.obs import trace as _obs_trace
 
-_USE_KERNELS = True
-
-
-def _env_interpret() -> bool:
-    """Pallas interpret mode from the environment: the real-TPU flip is
-    ``REPRO_PALLAS_INTERPRET=0`` (or false/off) — no code edit needed.
-    Default is interpret (this CPU container has no Mosaic backend)."""
-    v = os.environ.get("REPRO_PALLAS_INTERPRET", "1")
-    return v.strip().lower() not in ("0", "false", "off", "no")
-
-
-_INTERPRET = _env_interpret()
+_INTERPRET: bool | None = None       # None: follow the platform
 
 DISPATCH_COUNTS: collections.Counter = collections.Counter()
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
-def use_kernels(on: bool):
-    global _USE_KERNELS
-    _USE_KERNELS = on
-
-
 def interpret_mode() -> bool:
-    return _INTERPRET
+    """True when the readers run in the Pallas interpreter: by default
+    exactly when JAX's default backend is the CPU."""
+    return interpret_default() if _INTERPRET is None else _INTERPRET
 
 
-def set_interpret(on: bool):
-    """Flip interpret/compiled Pallas at RUNTIME (overrides the env default).
-
-    The jitted reader wrappers bake the flag in at trace time, so flipping
-    clears their jit caches — the next call retraces under the new mode.
-    """
+def set_interpret(on: bool | None):
+    """Override interpret mode at runtime (``None`` follows the platform
+    again).  The mode is a static argument of the jitted readers, so a flip
+    compiles a fresh variant instead of reusing the other mode's.
+    Interpret mode is refused off the CPU: it would hide the device."""
     global _INTERPRET
-    on = bool(on)
-    if on == _INTERPRET:
-        return
-    _INTERPRET = on
-    for fn in (_index_search_jit, _pax_scan_jit, _hail_read_jit,
-               _hail_read_ref_jit, _hail_read_batch_jit,
-               _hail_read_batch_ref_jit):
-        fn.clear_cache()
+    if on and not interpret_default():
+        raise ValueError("interpret mode runs kernels on the host; refused "
+                         f"on backend {jax.default_backend()!r}")
+    _INTERPRET = None if on is None else bool(on)
 
 
 def reset_stats():
@@ -131,63 +108,36 @@ def stats_scope(merge: bool = True):
         DISPATCH_COUNTS, TRACE_COUNTS = prev_d, prev_t
 
 
+@jax.jit
 def sort_block(keys: jax.Array, cols: dict[str, jax.Array]):
-    """Sort one block by key, permuting all PAX columns.
-    keys (blocks, n) -> (sorted_keys, permuted cols)."""
-    if _USE_KERNELS and keys.shape[-1] & (keys.shape[-1] - 1) == 0:
-        sorted_keys, perm = bitonic_sort(keys, interpret=_INTERPRET)
-    else:
-        sorted_keys, perm = jax.vmap(ref.sort_by_key)(keys)
-    out = {c: jnp.take_along_axis(v, perm, axis=1) for c, v in cols.items()}
-    return sorted_keys, out, perm
+    """Stable sort of each block by key, permuting all PAX columns: the same
+    stable XLA sort the eager upload runs (``core.index.sort_permutation``),
+    so adaptive builds, demotions and repairs reproduce an upload's layout
+    byte for byte.  keys (blocks, n) -> (sorted_keys, permuted cols, perm)."""
+    perm = jax.vmap(_idx.sort_permutation)(keys)
+    return (jnp.take_along_axis(keys, perm, axis=1),
+            {c: jnp.take_along_axis(v, perm, axis=1) for c, v in cols.items()},
+            perm)
 
 
 # -- jitted entry points: lo/hi TRACED, shapes/statics are the only cache keys
 
 
-@jax.jit
-def _index_search_jit(mins, lo, hi):
-    TRACE_COUNTS["index_search"] += 1
-    return _index_search(mins, lo, hi, interpret=_INTERPRET)
-
-
-@jax.jit
-def _pax_scan_jit(key_col, proj, lo, hi):
-    TRACE_COUNTS["pax_scan"] += 1
-    return _pax_scan(key_col, proj, lo, hi, interpret=_INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("partition_size",))
+@functools.partial(jax.jit, static_argnames=("partition_size", "interpret"))
 def _hail_read_jit(mins, keys, proj, bad, use_index, lo, hi,
-                   *, partition_size):
+                   *, partition_size, interpret):
     TRACE_COUNTS["hail_read"] += 1
     return _hail_read(mins, keys, proj, bad, use_index, lo, hi,
-                      partition_size=partition_size, interpret=_INTERPRET)
+                      partition_size=partition_size, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("partition_size",))
-def _hail_read_ref_jit(mins, keys, proj, bad, use_index, lo, hi,
-                       *, partition_size):
-    TRACE_COUNTS["hail_read_ref"] += 1
-    return ref.hail_read(mins, keys, proj, bad, use_index, lo, hi,
-                         partition_size=partition_size)
-
-
-@functools.partial(jax.jit, static_argnames=("partition_size",))
+@functools.partial(jax.jit, static_argnames=("partition_size", "interpret"))
 def _hail_read_batch_jit(mins, keys, proj, bad, use_index, lohi,
-                         *, partition_size):
+                         *, partition_size, interpret):
     TRACE_COUNTS["hail_read_batch"] += 1
     return _hail_read_batch(mins, keys, proj, bad, use_index, lohi,
                             partition_size=partition_size,
-                            interpret=_INTERPRET)
-
-
-@functools.partial(jax.jit, static_argnames=("partition_size",))
-def _hail_read_batch_ref_jit(mins, keys, proj, bad, use_index, lohi,
-                             *, partition_size):
-    TRACE_COUNTS["hail_read_batch_ref"] += 1
-    return ref.hail_read_batch(mins, keys, proj, bad, use_index, lohi,
-                               partition_size=partition_size)
+                            interpret=interpret)
 
 
 @jax.jit
@@ -222,20 +172,6 @@ def verify_root(mins, keys, *, partition_size: int) -> jax.Array:
     return _verify_root_jit(mins, keys, partition_size=partition_size)
 
 
-def index_search(mins: jax.Array, lo, hi) -> jax.Array:
-    DISPATCH_COUNTS["index_search"] += 1
-    if _USE_KERNELS:
-        return _index_search_jit(mins, lo, hi)
-    return ref.index_search(mins, lo, hi)
-
-
-def pax_scan(key_col: jax.Array, proj: jax.Array, lo, hi):
-    DISPATCH_COUNTS["pax_scan"] += 1
-    if _USE_KERNELS:
-        return _pax_scan_jit(key_col, proj, lo, hi)
-    return ref.pax_scan(key_col, proj, lo, hi)
-
-
 def hail_read(mins, keys, proj, bad, use_index, lo, hi, *,
               partition_size: int):
     """Fused split reader: ONE dispatch per call (== per split).
@@ -255,10 +191,11 @@ def hail_read(mins, keys, proj, bad, use_index, lo, hi, *,
     _obs_trace.instant("hail_read", track="kernels", cat="dispatch",
                        args={"index_blocks": n_idx,
                              "full_blocks": int(u.shape[0]) - n_idx})
-    fn = _hail_read_jit if _USE_KERNELS else _hail_read_ref_jit
-    return fn(mins, keys, proj, bad, jnp.asarray(u, jnp.int32),
-              jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-              partition_size=partition_size)
+    return _hail_read_jit(mins, keys, proj, bad, jnp.asarray(u, jnp.int32),
+                          jnp.asarray(lo, jnp.int32),
+                          jnp.asarray(hi, jnp.int32),
+                          partition_size=partition_size,
+                          interpret=interpret_mode())
 
 
 def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
@@ -284,40 +221,32 @@ def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
     _obs_trace.instant("hail_read_batch", track="kernels", cat="dispatch",
                        args={"queries": n_q, "index_blocks": n_idx,
                              "full_blocks": int(u.shape[0]) - n_idx})
-    fn = _hail_read_batch_jit if _USE_KERNELS else _hail_read_batch_ref_jit
-    return fn(mins, keys, proj, bad, jnp.asarray(u, jnp.int32),
-              jnp.asarray(lohi), partition_size=partition_size)
+    return _hail_read_batch_jit(mins, keys, proj, bad,
+                                jnp.asarray(u, jnp.int32), jnp.asarray(lohi),
+                                partition_size=partition_size,
+                                interpret=interpret_mode())
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_batch_reader(mesh, axes: tuple, partition_size: int,
-                          use_kernels: bool, interpret: bool):
+                          interpret: bool):
     """shard_map'd fused batch reader, compiled once per (mesh, axes,
-    partition_size, backend) — the kernel/interpret flags are CACHE KEYS
-    here (not baked globals), so ``set_interpret``/``use_kernels`` flips
-    pick a fresh entry without any cache clearing."""
-    try:
-        from jax import shard_map                      # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    partition_size, interpret mode)."""
     from jax.sharding import PartitionSpec as P
     spec = P(axes if len(axes) > 1 else axes[0])
 
     def local(mins, keys, proj, bad, use_index, lohi):
         TRACE_COUNTS["hail_read_sharded"] += 1
-        if use_kernels:
-            return _hail_read_batch(mins, keys, proj, bad, use_index, lohi,
-                                    partition_size=partition_size,
-                                    interpret=interpret)
-        return ref.hail_read_batch(mins, keys, proj, bad, use_index, lohi,
-                                   partition_size=partition_size)
+        return _hail_read_batch(mins, keys, proj, bad, use_index, lohi,
+                                partition_size=partition_size,
+                                interpret=interpret)
 
     # block dim sharded over the scan axes; the (Q, 2) ranges replicated.
-    # check_rep=False: outputs are per-shard block tiles, no replication
+    # check_vma=False: outputs are per-shard block tiles, no replication
     # invariant for the checker to prove through the pallas call.
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(spec, spec, spec, spec, spec, P()),
-                   out_specs=(spec, spec, spec), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec, spec, spec, P()),
+                       out_specs=(spec, spec, spec), check_vma=False)
     return jax.jit(fn)
 
 
@@ -341,16 +270,8 @@ def hail_read_batch_sharded(mins, keys, proj, bad, use_index, lohi, *,
                        args={"splits": int(n_splits),
                              "blocks": int(mins.shape[0]),
                              "axes": ",".join(axes)})
-    fn = _sharded_batch_reader(mesh, axes, partition_size,
-                               _USE_KERNELS, _INTERPRET)
+    fn = _sharded_batch_reader(mesh, axes, partition_size, interpret_mode())
     lohi = np.asarray(lohi, np.int32).reshape(-1, 2)
     return fn(mins, keys, proj, bad,
               jnp.asarray(np.asarray(use_index), jnp.int32),
               jnp.asarray(lohi))
-
-
-def attention(q, k, v, *, causal=True, window=None):
-    if _USE_KERNELS:
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=_INTERPRET)
-    return ref.attention(q, k, v, causal=causal, window=window)

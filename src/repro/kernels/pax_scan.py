@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _scan_kernel(lohi_ref, key_ref, proj_ref, mask_ref, out_ref, cnt_ref):
     lo = lohi_ref[0, 0]
@@ -31,7 +33,7 @@ def _scan_kernel(lohi_ref, key_ref, proj_ref, mask_ref, out_ref, cnt_ref):
 
 
 def pax_scan(key_col: jax.Array, proj: jax.Array, lo, hi,
-             *, row_tile: int = 1024, interpret: bool = True):
+             *, row_tile: int = 1024, interpret: bool | None = None):
     """key_col (rows,), proj (rows, C) -> (mask (rows,), masked proj, counts).
     lo/hi may be python ints or traced values (no per-query recompile).
     """
@@ -54,6 +56,6 @@ def pax_scan(key_col: jax.Array, proj: jax.Array, lo, hi,
         out_shape=[jax.ShapeDtypeStruct((rows,), jnp.bool_),
                    jax.ShapeDtypeStruct((rows, c), proj.dtype),
                    jax.ShapeDtypeStruct((rows // tr,), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(lohi, key_col, proj)
     return mask, out, cnt

@@ -7,16 +7,10 @@ import jax
 import jax.numpy as jnp
 
 
-def sort_by_key(keys: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """-> (sorted_keys, permutation). keys (n,) int32."""
-    perm = jnp.argsort(keys)
-    return keys[perm], perm.astype(jnp.int32)
-
-
 def index_search(mins: jax.Array, lo, hi) -> jax.Array:
     """mins (blocks, n_parts) sorted -> (blocks, 2) [p_first, p_last]."""
     first = jnp.maximum(
-        jnp.sum(mins <= lo, axis=-1).astype(jnp.int32) - 1, 0)
+        jnp.sum(mins < lo, axis=-1).astype(jnp.int32) - 1, 0)
     last = jnp.maximum(
         jnp.sum(mins <= hi, axis=-1).astype(jnp.int32) - 1, 0)
     return jnp.stack([first, last], axis=-1)
@@ -33,7 +27,7 @@ def hail_read(mins, keys, proj, bad, use_index, lo, hi, *,
               partition_size: int):
     """Fused split-reader oracle: per-block root lookup + pruned range scan.
 
-    mins (B,P), keys (B,R), proj (B,R,C), bad (B,R) bool, use_index (B,)
+    mins (B,P), keys (B,R), proj (B,C,R), bad (B,R) bool, use_index (B,)
     -> (mask (B,R) bool, masked proj, rows_read_frac (B,) f32)."""
     rows = keys.shape[1]
     pr = index_search(mins, lo, hi)                          # (B, 2)
@@ -43,7 +37,7 @@ def hail_read(mins, keys, proj, bad, use_index, lo, hi, *,
     r = jnp.arange(rows, dtype=jnp.int32)[None, :]
     in_range = (r >= r0[:, None]) & (r < r1[:, None])
     mask = (keys >= lo) & (keys <= hi) & in_range & ~bad
-    out = jnp.where(mask[..., None], proj, 0)
+    out = jnp.where(mask[:, None, :], proj, 0)
     frac = (r1 - r0).astype(jnp.float32) / rows
     return mask, out, frac
 
@@ -52,8 +46,8 @@ def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
                     partition_size: int):
     """Shared-scan batch oracle: Q range queries over one split at once.
 
-    lohi (Q, 2) -> (mask (B, R, Q) bool, proj masked by the union of the Q
-    masks (B, R, C), rows_read_frac (B, Q) f32) — the Q=1 slice matches
+    lohi (Q, 2) -> (mask (B, Q, R) bool, proj masked by the union of the Q
+    masks (B, C, R), rows_read_frac (B, Q) f32) — the Q=1 slice matches
     ``hail_read`` exactly."""
 
     def one(lo, hi):
@@ -62,8 +56,8 @@ def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
         return m, f
 
     mask_q, frac_q = jax.vmap(one)(lohi[:, 0], lohi[:, 1])   # (Q,B,R) (Q,B)
-    mask = jnp.moveaxis(mask_q, 0, -1)                       # (B, R, Q)
-    out = jnp.where(mask.any(axis=-1)[..., None], proj, 0)
+    mask = jnp.moveaxis(mask_q, 0, 1)                        # (B, Q, R)
+    out = jnp.where(mask.any(axis=1)[:, None, :], proj, 0)
     return mask, out, jnp.moveaxis(frac_q, 0, -1)
 
 

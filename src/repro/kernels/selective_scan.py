@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _scan_kernel(delta_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hout_ref,
                  h_scr, *, tc: int, n_chunks: int):
@@ -60,7 +62,7 @@ def _scan_kernel(delta_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hout_ref,
 
 def selective_scan(delta: jax.Array, x: jax.Array, b: jax.Array,
                    c: jax.Array, a: jax.Array, *, chunk: int = 64,
-                   d_block: int = 128, interpret: bool = True):
+                   d_block: int = 128, interpret: bool | None = None):
     """Mamba1 recurrence  h_t = exp(delta_t * A) h_{t-1} + delta_t B_t x_t,
     y_t = (h_t * C_t).sum(-1).
 
@@ -95,6 +97,6 @@ def selective_scan(delta: jax.Array, x: jax.Array, b: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((bs, t, d), delta.dtype),
                    jax.ShapeDtypeStruct((bs, d, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dblk, n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(delta, x, b, c, a)
     return y, h_final

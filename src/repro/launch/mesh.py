@@ -11,11 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 wants explicit axis_types; 0.4.x has no AxisType at all
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,6 +10,7 @@ non-zero (assertion) on any divergence; prints PASS lines the test asserts.
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"     # never contend for an accelerator
 
 import math  # noqa: E402
 import numpy as np  # noqa: E402

@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ref
-from repro.kernels.block_sort import bitonic_sort
+from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.hail_reader import hail_read_batch
 from repro.kernels.index_search import index_search
 from repro.kernels.pax_scan import pax_scan
 
@@ -16,28 +16,37 @@ KEY = jax.random.PRNGKey(0)
 
 
 # ---------------------------------------------------------------------------
-# block_sort
+# sort_block: the one stable sort behind upload, builds, demotion and repair
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("blocks,n", [(1, 64), (4, 256), (2, 1024)])
-def test_bitonic_sort_shapes(blocks, n):
+@pytest.mark.parametrize("blocks,n", [(1, 64), (4, 256), (2, 1000)])
+def test_sort_block_shapes(blocks, n):
     keys = jax.random.randint(KEY, (blocks, n), -1000, 1000, dtype=jnp.int32)
-    sk, perm = bitonic_sort(keys)
+    payload = jnp.arange(blocks * n, dtype=jnp.int32).reshape(blocks, n)
+    sk, cols, perm = ops.sort_block(keys, {"p": payload})
     np.testing.assert_array_equal(np.asarray(sk), np.sort(np.asarray(keys), 1))
-    # perm is a valid permutation reproducing the sort
     np.testing.assert_array_equal(
-        np.asarray(jnp.take_along_axis(keys, perm, 1)), np.asarray(sk))
-    assert (np.sort(np.asarray(perm), 1) == np.arange(n)).all()
+        np.asarray(perm), np.argsort(np.asarray(keys), 1, kind="stable"))
+    np.testing.assert_array_equal(
+        np.asarray(cols["p"]),
+        np.take_along_axis(np.asarray(payload), np.asarray(perm), 1))
 
 
 @settings(max_examples=10, deadline=None)
-@given(st.integers(0, 2**31 - 1), st.sampled_from([32, 128]))
-def test_bitonic_sort_property(seed, n):
+@given(st.integers(0, 2**31 - 1), st.sampled_from([32, 128, 96]))
+def test_sort_block_property(seed, n):
+    """Many ties: the permutation is the STABLE one, identical to the eager
+    upload's ``index.sort_permutation`` — a rebuilt block reproduces an
+    uploaded one byte for byte."""
+    from repro.core import index as idx
     r = np.random.default_rng(seed)
-    keys = jnp.asarray(r.integers(-5, 5, (2, n)).astype(np.int32))  # many ties
-    sk, perm = bitonic_sort(keys)
-    np.testing.assert_array_equal(np.asarray(sk), np.sort(np.asarray(keys), 1))
+    keys = jnp.asarray(r.integers(-5, 5, (2, n)).astype(np.int32))
+    _, _, perm = ops.sort_block(keys, {})
+    want = jax.vmap(idx.sort_permutation)(keys)
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(perm), np.argsort(np.asarray(keys), 1, kind="stable"))
 
 
 # ---------------------------------------------------------------------------
@@ -93,6 +102,67 @@ def test_pax_scan_dtypes():
         m, o, c = pax_scan(kc, pj, 0, 500, row_tile=128)
         rm, ro, rc = ref.pax_scan(kc, pj, 0, 500)
         np.testing.assert_array_equal(np.asarray(o), np.asarray(ro))
+
+
+# ---------------------------------------------------------------------------
+# hail_reader: the fused shared-scan reader vs its jnp oracle
+# ---------------------------------------------------------------------------
+
+
+def _reader_inputs(seed, b, rows, c, n_q, part):
+    r = np.random.default_rng(seed)
+    keys = np.sort(r.integers(0, 1000, (b, rows)), 1).astype(np.int32)
+    return (jnp.asarray(keys[:, ::part]), jnp.asarray(keys),
+            jnp.asarray(r.integers(-99, 99, (b, c, rows)).astype(np.int32)),
+            jnp.asarray(r.random((b, rows)) < 0.01),
+            jnp.asarray(np.arange(b) % 2, jnp.int32),   # index + full scans
+            jnp.asarray(np.sort(r.integers(0, 1000, (n_q, 2)), 1), jnp.int32))
+
+
+def _assert_reader_matches_oracle(args, part, row_tile):
+    got = hail_read_batch(*args, partition_size=part, interpret=True,
+                          row_tile=row_tile)
+    want = ref.hail_read_batch(*args, partition_size=part)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("b,rows,c,n_q,part,row_tile", [
+    (3, 64, 2, 1, 32, 32768),        # rows not a multiple of 128: (1, R)
+    (2, 1024, 3, 3, 128, 32768),     # one lane-dense tile per block
+    (2, 8192, 2, 8, 1024, 4096),     # two tiles: pruning between them
+])
+def test_fused_reader_matches_oracle(b, rows, c, n_q, part, row_tile):
+    args = _reader_inputs(rows, b, rows, c, n_q, part)
+    _assert_reader_matches_oracle(args, part, row_tile)
+
+
+@settings(max_examples=8, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.sampled_from([1, 2, 5]))
+def test_fused_reader_property(seed, n_q):
+    args = _reader_inputs(seed, 2, 8192, 2, n_q, 512)
+    _assert_reader_matches_oracle(args, 512, 4096)
+
+
+@pytest.mark.parametrize("lo,hi", [(1, 1), (2, 7), (0, 10)])
+def test_index_scan_keeps_keys_spanning_partitions(lo, hi):
+    """A key equal to lo can end the partition BEFORE the first partition
+    whose min is lo; the lookup must start there or those rows are lost
+    (first seen at 2^19-row blocks, where each visitDate repeats ~100x)."""
+    from repro.core import index as idx
+    part = 128
+    keys = np.repeat(np.arange(11, dtype=np.int32), 100)[:1024][None]
+    want = (keys >= lo) & (keys <= hi)
+    mins = jnp.asarray(keys[:, ::part])
+    got = idx.index_scan_mask(jnp.asarray(keys[0]), mins[0], lo, hi, part)
+    np.testing.assert_array_equal(np.asarray(got), want[0])
+    mask, _, _ = hail_read_batch(
+        mins, jnp.asarray(keys), jnp.asarray(keys)[:, None],
+        jnp.zeros(keys.shape, bool), jnp.ones((1,), jnp.int32),
+        jnp.asarray([[lo, hi]], jnp.int32), partition_size=part,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(mask[:, 0]), want)
 
 
 # ---------------------------------------------------------------------------

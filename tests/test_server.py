@@ -502,23 +502,30 @@ def test_flush_tasks_throughput_bridge(served_store):
 # ---------------------------------------------------------------------------
 
 
-def test_interpret_env_flag_parsing(monkeypatch):
-    for raw, want in [("1", True), ("true", True), ("", True),
-                      ("0", False), ("false", False), ("OFF", False),
-                      ("No", False)]:
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", raw)
-        assert ops._env_interpret() is want, raw
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    assert ops._env_interpret() is True      # container default: interpret
+def test_interpret_default_follows_backend(monkeypatch):
+    """No environment knob: interpret mode is on exactly where JAX's default
+    backend is the CPU (these tests), off on an accelerator — where asking
+    for it is refused rather than silently hiding the device."""
+    import jax
+    from repro.kernels import interpret_default
+    assert jax.default_backend() == "cpu"
+    assert interpret_default() is True
+    assert ops.interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_default() is False
+    assert ops.interpret_mode() is False
+    with pytest.raises(ValueError):
+        ops.set_interpret(True)
+    assert ops.interpret_mode() is False
 
 
 def test_set_interpret_flips_and_clears_caches(served_store):
     assert ops.interpret_mode() is True
     try:
-        ops.set_interpret(False)             # the real-TPU flip, at runtime
+        ops.set_interpret(False)             # compiled Mosaic, at runtime
         assert ops.interpret_mode() is False
     finally:
-        ops.set_interpret(True)
+        ops.set_interpret(None)              # back to following the backend
     assert ops.interpret_mode() is True
     # reader still correct after the cache-clearing round trip
     qp = q.plan(served_store, QUERIES[0])
