@@ -324,8 +324,6 @@ def _gather_replica_inputs(store: BlockStore, rid: int, bsel: np.ndarray,
     if cache is not None:
         hit = cache.get(key)
         if hit is not None:
-            obs_trace.instant("block_cache_hit", track="cache",
-                              args={"replica": rid, "blocks": len(bsel)})
             return hit
     rep = store.replicas[rid]
     # verify on FILL, not on hit: cached gathers are separate device arrays
@@ -381,6 +379,23 @@ def _gather_split_inputs(store: BlockStore, qplan: QueryPlan,
             jnp.concatenate(proj_p, axis=0)[inv],
             jnp.concatenate(bad_p, axis=0)[inv],
             np.concatenate(uidx_p, axis=0)[inv])
+
+
+def _gather_traced(store: BlockStore, qplan: QueryPlan, ids: np.ndarray,
+                   col: str, proj_cols: tuple, n_queries: int):
+    """``_gather_split_inputs`` of a shared scan inside a ``gather`` span,
+    whose arguments count the split's block-cache hits and misses."""
+    cache = store.block_cache
+    with obs_trace.span("gather", track="server") as args:
+        if args is None or cache is None:
+            return _gather_split_inputs(store, qplan, ids, col, proj_cols,
+                                        n_queries)
+        h0, m0 = cache.stats.hits, cache.stats.misses
+        out = _gather_split_inputs(store, qplan, ids, col, proj_cols,
+                                   n_queries)
+        args.update(cache_hits=cache.stats.hits - h0,
+                    cache_misses=cache.stats.misses - m0)
+        return out
 
 
 def attribution_groups(qplan: QueryPlan, block_ids: Sequence[int]
@@ -482,21 +497,26 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
     if len(ids) == 0:
         return [_empty_read(store, proj_cols, rows) for _ in queries], 0
 
-    mins, keys, proj_arr, bad, uidx = _gather_split_inputs(
-        store, qplan, ids, col, proj_cols, n_queries=len(queries))
+    mins, keys, proj_arr, bad, uidx = _gather_traced(
+        store, qplan, ids, col, proj_cols, len(queries))
     lohi = np.asarray([[qq.filter[1], qq.filter[2]] for qq in queries],
                       np.int32)
-    mask, out, frac = ops.hail_read_batch(mins, keys, proj_arr, bad, uidx,
-                                          lohi,
-                                          partition_size=store.partition_size)
-    cols = {c: out[:, j] for j, c in enumerate(proj_cols)}
-    results = [
-        ReadResult(cols=cols, mask=mask[:, qi],
-                   rows_read_frac=frac[:, qi],
-                   bytes_read=frac[:, qi].sum() * col_bytes
-                   * (1 + len(proj)))
-        for qi in range(len(queries))]
-    shared_bytes = frac.max(axis=1).sum() * col_bytes * (1 + len(proj))
+    with obs_trace.span("issue", track="server") as args:
+        if args is not None:
+            n_idx = int(uidx.astype(bool).sum())
+            args.update(queries=len(queries), index_blocks=n_idx,
+                        full_blocks=len(ids) - n_idx)
+        mask, out, frac = ops.hail_read_batch(
+            mins, keys, proj_arr, bad, uidx, lohi,
+            partition_size=store.partition_size)
+        cols = {c: out[:, j] for j, c in enumerate(proj_cols)}
+        results = [
+            ReadResult(cols=cols, mask=mask[:, qi],
+                       rows_read_frac=frac[:, qi],
+                       bytes_read=frac[:, qi].sum() * col_bytes
+                       * (1 + len(proj)))
+            for qi in range(len(queries))]
+        shared_bytes = frac.max(axis=1).sum() * col_bytes * (1 + len(proj))
     return results, shared_bytes
 
 
@@ -516,8 +536,7 @@ def gather_shared_scan_inputs(store: BlockStore,
     col = queries[0].filter_col
     assert col is not None and store.layout == "pax"
     proj_cols = tuple(queries[0].projection) + (ROWID,)
-    return _gather_split_inputs(store, qplan, ids, col, proj_cols,
-                                n_queries=len(queries))
+    return _gather_traced(store, qplan, ids, col, proj_cols, len(queries))
 
 
 def read_hail_batch_sharded(store: BlockStore,

@@ -218,9 +218,6 @@ def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
     n_idx = int(u.astype(bool).sum())
     DISPATCH_COUNTS["index_scan_blocks"] += n_q * n_idx
     DISPATCH_COUNTS["full_scan_blocks"] += n_q * (u.shape[0] - n_idx)
-    _obs_trace.instant("hail_read_batch", track="kernels", cat="dispatch",
-                       args={"queries": n_q, "index_blocks": n_idx,
-                             "full_blocks": int(u.shape[0]) - n_idx})
     return _hail_read_batch_jit(mins, keys, proj, bad,
                                 jnp.asarray(u, jnp.int32), jnp.asarray(lohi),
                                 partition_size=partition_size,

@@ -5,8 +5,9 @@ Two clocks, two trace processes:
 
 * **pid 1 "hail (measured wall)"** — real ``time.perf_counter`` sections:
   upload phases, flush lifecycle (result-cache probe, batching, plan,
-  per-split dispatch, verify, cache fill, ticket finalize), adaptive
-  builds, demotions, quarantine/repair instants, scrubber ticks.
+  per-split dispatch — prune, gather, issue — then per-split wait and
+  per-ticket finalize; verify and cache fill), adaptive builds,
+  demotions, quarantine/repair instants, scrubber ticks.
 * **pid 2 "cluster (simulated)"** — the deterministic simulated timeline:
   ``run_schedule`` task runs become per-node tracks, ``ServerFrontend``
   queries become per-tenant slices from arrival to modeled completion,
@@ -20,6 +21,13 @@ jit'd code (the hooks live on the host side of every dispatch).  Install
 with ``tracer = trace.install()``, export with ``tracer.export(path)``,
 remove with ``trace.uninstall()``.
 
+A ``span`` yields its argument dict (``None`` when tracing is off), so a
+caller builds arguments only when traced and may add ones known only at
+the end of the body: ``with span("x") as a: ...; if a is not None:
+a["rows"] = n``.  Each span also enters a ``jax.profiler.TraceAnnotation``
+of the same name, which puts it into a running profiler trace
+(``.xplane.pb``) on the profiler's own clock, beside the device ops.
+
 ``validate_chrome_trace`` checks the exported object against the parts of
 the Chrome trace-event contract Perfetto actually enforces: known phases,
 numeric non-negative ``ts``, non-negative ``dur`` on ``X`` events, and
@@ -32,6 +40,8 @@ import contextlib
 import json
 import time
 from typing import Optional
+
+import jax
 
 PID_WALL = 1     # measured perf_counter sections
 PID_SIM = 2      # simulated scheduler/frontend timeline
@@ -74,19 +84,21 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "hail", track: str = "main",
              args: Optional[dict] = None):
-        """B/E span on the measured clock around a ``with`` body."""
+        """B/E span on the measured clock around a ``with`` body, mirrored
+        as a profiler ``TraceAnnotation``; yields the B event's argument
+        dict, which the body may extend."""
         tid = self._tid(PID_WALL, track)
-        ev = {"ph": "B", "pid": PID_WALL, "tid": tid, "name": name,
-              "cat": cat, "ts": self.now_us()}
-        if args:
-            ev["args"] = dict(args)
-        self.events.append(ev)
-        try:
-            yield self
-        finally:
-            self.events.append({"ph": "E", "pid": PID_WALL, "tid": tid,
+        ev_args = dict(args) if args else {}
+        with jax.profiler.TraceAnnotation(name):
+            self.events.append({"ph": "B", "pid": PID_WALL, "tid": tid,
                                 "name": name, "cat": cat,
-                                "ts": self.now_us()})
+                                "ts": self.now_us(), "args": ev_args})
+            try:
+                yield ev_args
+            finally:
+                self.events.append({"ph": "E", "pid": PID_WALL, "tid": tid,
+                                    "name": name, "cat": cat,
+                                    "ts": self.now_us()})
 
     def instant(self, name: str, *, cat: str = "hail", track: str = "main",
                 args: Optional[dict] = None):

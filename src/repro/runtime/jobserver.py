@@ -333,6 +333,14 @@ class HailServer:
         is revived at the end, so one flush exercises both the mid-batch
         retry path and cross-batch re-planning.
         """
+        with obs_trace.span("flush", track="server") as args:
+            stats = self._flush(fail_node_at)
+            if args is not None:
+                args.update(queries=stats.n_queries,
+                            batches=stats.n_batches, splits=stats.n_splits)
+        return stats
+
+    def _flush(self, fail_node_at: Optional[float]) -> FlushStats:
         tickets, self._pending = self._pending, []
         # ONE governor job boundary per flush (not per batch): the flush is
         # the user-visible workload unit, so a never-before-seen column
@@ -347,8 +355,9 @@ class HailServer:
         t0 = time.perf_counter()
         # tier 2 first: a repeated/subsumed range skips batching, planning
         # and the fused scan entirely — only the misses get batched below
-        with obs_trace.span("result_cache_probe", track="server",
-                            args={"queries": len(tickets)}):
+        with obs_trace.span("result_cache_probe", track="server") as args:
+            if args is not None:
+                args["queries"] = len(tickets)
             missed = [t for t in tickets
                       if not self._serve_from_result_cache(t)]
         with obs_trace.span("batching", track="server"):
@@ -373,9 +382,13 @@ class HailServer:
         retries: collections.Counter = collections.Counter()
         try:
             for batch in batches:
-                t_b = time.perf_counter()
                 try:
-                    self._run_batch(batch, stats, budget, fail, retries, t0)
+                    with obs_trace.span("batch", track="server") as args:
+                        if args is not None:
+                            args.update(width=len(batch),
+                                        tickets=[t.ticket_id for t in batch])
+                        self._run_batch(batch, stats, budget, fail, retries,
+                                        t0)
                 except UnrecoverableDataError as e:
                     # the failed batch terminates TYPED — its not-yet-
                     # finalized tickets get status="failed" (never stranded
@@ -399,10 +412,6 @@ class HailServer:
                         del stats.batch_of_split[-extra:]
                         del stats.queries_of_split[-extra:]
                         del stats.split_scan_modes[-extra:]
-                finally:
-                    obs_trace.complete_wall(
-                        "batch", t_b, time.perf_counter() - t_b,
-                        track="server", args={"width": len(batch)})
         finally:
             # lifecycle invariants hold even when a batch dies terminally:
             # the injected-failure node is revived and the boundary scrub
@@ -432,10 +441,6 @@ class HailServer:
         if rc:
             stats.result_cache_hits = rc.stats.hits - rc_h0
             stats.result_cache_misses = rc.stats.misses - rc_m0
-        obs_trace.complete_wall("flush", t0, stats.wall_s, track="server",
-                                args={"queries": stats.n_queries,
-                                      "batches": stats.n_batches,
-                                      "splits": stats.n_splits})
         obs_metrics.observe_flush(stats,
                                   tenants=[t.tenant for t in tickets])
         # one shared EXPLAIN context per flush: every ticket (result-cache
@@ -553,8 +558,9 @@ class HailServer:
         store = self.store
         queries = [t.query for t in batch]
         query0 = queries[0]
-        with obs_trace.span("plan", track="server",
-                            args={"width": len(batch)}):
+        with obs_trace.span("plan", track="server") as args:
+            if args is not None:
+                args["width"] = len(batch)
             qplan = q.plan(store, query0)
         splits = (hail_splits(store, qplan, self.config.cluster.map_slots)
                   if store.layout == "pax" else hadoop_splits(store, qplan))
@@ -598,9 +604,12 @@ class HailServer:
         def flush_wave():
             if not wave:
                 return
-            out = q.read_hail_batch_sharded(store, queries,
-                                            [g for _, g in wave],
-                                            self.config.mesh, scan_axes)
+            with obs_trace.span("issue", track="server") as args:
+                if args is not None:
+                    args.update(queries=len(queries), splits=len(wave))
+                out = q.read_hail_batch_sharded(store, queries,
+                                                [g for _, g in wave],
+                                                self.config.mesh, scan_axes)
             for (live_qis, _), (res, shared) in zip(wave, out):
                 dispatched.append((res, shared, time.perf_counter(),
                                    live_qis))
@@ -622,58 +631,65 @@ class HailServer:
                         break
                 sp = pending[i]
                 i += 1
-                live = self._live_members(qplan, sp, queries)
-                if not live:
-                    # DEAD split: no member's answer depends on it, and a
-                    # dead split is all-index-scan so no piggyback build
-                    # rides it — skip the dispatch entirely
-                    continue
-                try:
+                with obs_trace.span("dispatch", track="server") as args:
+                    with obs_trace.span("prune", track="server"):
+                        live = self._live_members(qplan, sp, queries)
+                    if args is not None:
+                        args.update(split=i - 1,
+                                    blocks=[int(b) for b in sp.block_ids],
+                                    live=[batch[qi].ticket_id for qi in live])
+                    if not live:
+                        # DEAD split: no member's answer depends on it, and a
+                        # dead split is all-index-scan so no piggyback build
+                        # rides it — skip the dispatch entirely
+                        continue
+                    try:
+                        if use_sharded:
+                            gathered = q.gather_shared_scan_inputs(
+                                store, queries, qplan, list(sp.block_ids))
+                            res = shared = None
+                        else:
+                            res, shared = self._read_batch(queries, qplan,
+                                                           list(sp.block_ids))
+                    except CorruptBlockError as e:
+                        # quarantine at the namenode, re-plan against the
+                        # smaller replica set, re-queue this split's blocks as
+                        # per-block retries — identical recovery to run_job's
+                        store.quarantine_block(e.replica_id, e.block_id)
+                        stats.blocks_quarantined += 1
+                        stats.corrupt_retries += 1
+                        note_retries(sp.block_ids)
+                        qplan = q.plan(store, query0)
+                        pending.extend(
+                            Split(node=int(qplan.nodes[b]), block_ids=(b,),
+                                  index_scan=bool(qplan.index_scan[b]))
+                            for b in sp.block_ids)
+                        continue
                     if use_sharded:
-                        gathered = q.gather_shared_scan_inputs(
-                            store, queries, qplan, list(sp.block_ids))
-                        res = shared = None
+                        wave.append((tuple(live), gathered))
                     else:
-                        res, shared = self._read_batch(queries, qplan,
-                                                       list(sp.block_ids))
-                except CorruptBlockError as e:
-                    # quarantine at the namenode, re-plan against the
-                    # smaller replica set, re-queue this split's blocks as
-                    # per-block retries — identical recovery to run_job's
-                    store.quarantine_block(e.replica_id, e.block_id)
-                    stats.blocks_quarantined += 1
-                    stats.corrupt_retries += 1
-                    note_retries(sp.block_ids)
-                    qplan = q.plan(store, query0)
-                    pending.extend(
-                        Split(node=int(qplan.nodes[b]), block_ids=(b,),
-                              index_scan=bool(qplan.index_scan[b]))
-                        for b in sp.block_ids)
-                    continue
-                if use_sharded:
-                    wave.append((tuple(live), gathered))
-                else:
-                    dispatched.append((res, shared, time.perf_counter(),
-                                       tuple(live)))
-                d_wall, demote_pending = demote_pending, 0.0
-                b_wall = 0.0
-                if adapt_rid is not None and budget["left"] > 0:
-                    built, demoted, b_wall, dd_wall = mr.piggyback_build(
-                        store, sp, adapt_rid, adapt_col, budget["left"])
-                    budget["left"] -= built
-                    stats.blocks_indexed += built
-                    stats.blocks_demoted += demoted
-                    d_wall += dd_wall
-                stats.build_s.append(b_wall)
-                stats.demote_s.append(d_wall)
-                stats.batch_of_split.append(len(batch))
-                stats.queries_of_split.append(
-                    tuple(batch[qi].ticket_id for qi in live))
-                n_idx = sum(bool(qplan.index_scan[b]) for b in sp.block_ids)
-                stats.split_scan_modes.append(
-                    (n_idx, len(sp.block_ids) - n_idx))
-                if use_sharded and len(wave) == n_dev:
-                    flush_wave()
+                        dispatched.append((res, shared, time.perf_counter(),
+                                           tuple(live)))
+                    d_wall, demote_pending = demote_pending, 0.0
+                    b_wall = 0.0
+                    if adapt_rid is not None and budget["left"] > 0:
+                        built, demoted, b_wall, dd_wall = mr.piggyback_build(
+                            store, sp, adapt_rid, adapt_col, budget["left"])
+                        budget["left"] -= built
+                        stats.blocks_indexed += built
+                        stats.blocks_demoted += demoted
+                        d_wall += dd_wall
+                    stats.build_s.append(b_wall)
+                    stats.demote_s.append(d_wall)
+                    stats.batch_of_split.append(len(batch))
+                    stats.queries_of_split.append(
+                        tuple(batch[qi].ticket_id for qi in live))
+                    n_idx = sum(bool(qplan.index_scan[b])
+                                for b in sp.block_ids)
+                    stats.split_scan_modes.append(
+                        (n_idx, len(sp.block_ids) - n_idx))
+                    if use_sharded and len(wave) == n_dev:
+                        flush_wave()
             flush_wave()          # ragged final wave
         finally:
             if demote_pending > 0.0:
@@ -711,29 +727,35 @@ class HailServer:
                 recipe = None          # can't describe a fresh scan: no fill
 
         per_query: list[list] = [[] for _ in queries]   # live ReadResults
+        copied: set = set()       # ids of arrays a traced finalize copied
 
         def finalize(qi: int):
-            ticket, parts = batch[qi], per_query[qi]
-            masks = [np.asarray(r.mask).reshape(-1) for r in parts]
-            rows: dict[str, np.ndarray] = {}
-            for c in tuple(ticket.query.projection) + (q.ROWID,):
-                rows[c] = np.concatenate(
-                    [np.asarray(r.cols[c]).reshape(-1)[m]
-                     for r, m in zip(parts, masks)]) if parts else \
-                    self._empty_col(c)
-            n_rows = int(sum(m.sum() for m in masks))
-            ticket.result = QueryResult(n_rows=n_rows, rows=rows,
-                                        batch_size=len(batch),
-                                        n_splits=n_splits)
-            ticket.status = "done"
-            stats.query_done_s[ticket.ticket_id] = time.perf_counter() - t0
-            obs_trace.instant("finalize", track="server",
-                              args={"ticket": ticket.ticket_id,
-                                    "rows": n_rows})
-            if recipe is not None:
-                col, lo, hi = ticket.query.filter
-                rc.put(col, lo, hi, tuple(ticket.query.projection),
-                       store.version, rows, recipe)
+            with obs_trace.span("finalize", track="server") as args:
+                ticket, parts = batch[qi], per_query[qi]
+                names = tuple(ticket.query.projection) + (q.ROWID,)
+                masks = [np.asarray(r.mask).reshape(-1) for r in parts]
+                rows: dict[str, np.ndarray] = {}
+                for c in names:
+                    rows[c] = np.concatenate(
+                        [np.asarray(r.cols[c]).reshape(-1)[m]
+                         for r, m in zip(parts, masks)]) if parts else \
+                        self._empty_col(c)
+                n_rows = int(sum(m.sum() for m in masks))
+                ticket.result = QueryResult(n_rows=n_rows, rows=rows,
+                                            batch_size=len(batch),
+                                            n_splits=n_splits)
+                ticket.status = "done"
+                stats.query_done_s[ticket.ticket_id] = \
+                    time.perf_counter() - t0
+                if recipe is not None:
+                    col, lo, hi = ticket.query.filter
+                    rc.put(col, lo, hi, tuple(ticket.query.projection),
+                           store.version, rows, recipe)
+                if args is not None:
+                    args.update(
+                        ticket=ticket.ticket_id, rows=n_rows,
+                        d2h_bytes=_first_copy_bytes(parts, names, copied),
+                        answer_bytes=sum(v.nbytes for v in rows.values()))
 
         remaining = [0] * len(queries)     # live splits still outstanding
         for _, _, _, live in dispatched:
@@ -743,21 +765,32 @@ class HailServer:
             if remaining[qi] == 0:
                 finalize(qi)               # live on nothing: done at once
         for res, shared, t_disp, live in dispatched:
-            jax.block_until_ready(res[0].mask)
-            split_wall = time.perf_counter() - t_disp
+            with obs_trace.span("wait", track="server") as args:
+                if args is not None:
+                    args["live"] = [batch[qi].ticket_id for qi in live]
+                jax.block_until_ready(res[0].mask)
+                split_wall = time.perf_counter() - t_disp
+                stats.bytes_read += int(shared)
             stats.split_s.append(split_wall)
-            obs_trace.complete_wall("split", t_disp, split_wall,
-                                    track="server",
-                                    args={"batch_width": len(batch),
-                                          "queries": [batch[qi].ticket_id
-                                                      for qi in live]})
-            stats.bytes_read += int(shared)
             for qi in live:
                 per_query[qi].append(res[qi])
                 remaining[qi] -= 1
                 if remaining[qi] == 0:
                     finalize(qi)
 
+
+def _first_copy_bytes(parts, names, copied: set) -> int:
+    """Bytes of the device arrays among ``parts``' masks and ``names``
+    columns that no earlier finalize of the batch copied to the host
+    (``copied`` holds their ids).  A ``jax.Array`` keeps its host copy, so
+    the projection columns a batch shares are paid for once."""
+    n = 0
+    for r in parts:
+        for a in (r.mask, *(r.cols[c] for c in names)):
+            if isinstance(a, jax.Array) and id(a) not in copied:
+                copied.add(id(a))
+                n += a.nbytes
+    return n
 
 # ---------------------------------------------------------------------------
 # Async latency-SLO frontend (simulated-clock event loop over HailServer)

@@ -215,8 +215,8 @@ def test_traced_flush_exports_valid_chrome_trace(obs_store, tmp_path):
     evs = exported["traceEvents"]
     names = {e["name"] for e in evs}
     # flush lifecycle on the measured wall
-    assert {"flush", "plan", "result_cache_probe", "batching", "split",
-            "verify_blocks", "finalize"} <= names
+    assert {"flush", "plan", "result_cache_probe", "batching", "dispatch",
+            "wait", "verify_blocks", "finalize"} <= names
     # simulated timeline: scheduler node tracks + per-tenant query slices
     sim_tracks = {e["args"]["name"] for e in evs
                   if e["ph"] == "M" and e["name"] == "thread_name"
@@ -229,6 +229,146 @@ def test_traced_flush_exports_valid_chrome_trace(obs_store, tmp_path):
     started = {e["id"] for e in flows if e["ph"] == "s"}
     finished = {e["id"] for e in flows if e["ph"] == "f"}
     assert finished and finished <= started
+
+
+def _spans(events, name):
+    """(start, end, args) of each closed B/E ``name`` span, by start."""
+    open_, out = {}, []
+    for ev in events:
+        if ev.get("name") != name:
+            continue
+        if ev["ph"] == "B":
+            open_.setdefault(ev["tid"], []).append(ev)
+        elif ev["ph"] == "E":
+            b = open_[ev["tid"]].pop()
+            out.append((b["ts"], ev["ts"], b["args"]))
+    return sorted(out, key=lambda sp: sp[0])
+
+
+def _traced_flush(server, queries):
+    """Submit ``queries``, flush once under a fresh tracer.  -> (tracer,
+    tickets, FlushStats)."""
+    tickets = [server.submit(qq) for qq in queries]
+    tracer = obs_trace.install()
+    try:
+        stats = server.flush()
+    finally:
+        obs_trace.uninstall()
+    return tracer, tickets, stats
+
+
+def test_traced_flush_span_tree(obs_store):
+    """Every step of a flush has its span, nested as the work is: prune,
+    gather and issue inside a split's dispatch, one wait per dispatched
+    split, one finalize per answered ticket, the ticket ids carried."""
+    server = js.HailServer(obs_store, js.ServerConfig(
+        max_batch=4, cluster=CLUSTER, result_cache=False))
+    # the second batch asks for dates past every block's last key: each
+    # of its splits is pruned (dead) and its tickets finalize at once
+    past = [q.HailQuery(filter=("visitDate", lo, lo + 10),
+                        projection=("sourceIP",)) for lo in (1 << 20, 1 << 21)]
+    queries = EXPLAIN_QUERIES + past
+    tracer, tickets, fs = _traced_flush(server, queries)
+    assert obs_trace.validate_chrome_trace(tracer.export()) == []
+    evs = tracer.events
+    assert not [e for e in evs if e["ph"] in ("X", "i")
+                and e["name"] in ("flush", "batch", "split", "finalize",
+                                  "block_cache_hit", "hail_read_batch")]
+    (flush,) = _spans(evs, "flush")
+    batches = _spans(evs, "batch")
+    assert len(batches) == fs.n_batches == 2
+    dispatches = _spans(evs, "dispatch")
+    for name in ("prune", "gather", "issue"):
+        for s, e, _ in _spans(evs, name):
+            assert any(ds <= s and e <= de for ds, de, _ in dispatches), name
+    assert len(_spans(evs, "prune")) == len(dispatches)
+    live = [a["live"] for _, _, a in dispatches]
+    assert [] in live, "the flush should hold a dead split"
+    assert sum(bool(lv) for lv in live) == fs.n_splits
+    assert len(_spans(evs, "wait")) == fs.n_splits
+    assert len(_spans(evs, "issue")) == fs.n_splits
+    for s, e, a in dispatches + _spans(evs, "wait"):
+        assert any(bs <= s and e <= be and set(a["live"]) <= set(ba["tickets"])
+                   for bs, be, ba in batches)
+    assert all(flush[0] <= s and e <= flush[1] for s, e, _ in batches)
+    fin = _spans(evs, "finalize")
+    answered = [t for t in tickets if t.status == "done"]
+    assert len(fin) == len(answered) == len(queries)
+    assert sorted(a["ticket"] for _, _, a in fin) == \
+        sorted(t.ticket_id for t in answered)
+    assert {tuple(b["tickets"]) for _, _, b in batches} == \
+        {(0, 1, 2, 3), (4, 5)}
+    assert sum(a["cache_hits"] + a["cache_misses"]
+               for _, _, a in _spans(evs, "gather")) >= fs.n_splits
+
+
+def test_finalize_counts_each_device_array_once(obs_store, monkeypatch):
+    """``d2h_bytes``: the nbytes of the distinct device arrays the finalizes
+    copied to the host (a batch's shared projection columns once);
+    ``answer_bytes``: rows x (projected columns + row id) x 4."""
+    import jax
+    server = js.HailServer(obs_store, js.ServerConfig(
+        max_batch=4, cluster=CLUSTER, result_cache=False))
+    produced, copied = set(), {}
+    read = q.read_hail_batch
+
+    def recording_read(*a, **kw):
+        res, shared = read(*a, **kw)
+        for r in res:
+            produced.update(id(x) for x in (r.mask, *r.cols.values()))
+        return res, shared
+
+    class HostCopies:                  # numpy as the server sees it
+        def __getattr__(self, k):
+            return getattr(np, k)
+
+        def asarray(self, a, *args, **kw):
+            if isinstance(a, jax.Array):
+                copied[id(a)] = a       # held: ids stay distinct
+            return np.asarray(a, *args, **kw)
+
+    monkeypatch.setattr(q, "read_hail_batch", recording_read)
+    monkeypatch.setattr(js, "np", HostCopies())
+    tracer, tickets, fs = _traced_flush(server, EXPLAIN_QUERIES)
+    fin = _spans(tracer.events, "finalize")
+    want = sum(a.nbytes for k, a in copied.items() if k in produced)
+    assert want > 0
+    assert sum(a["d2h_bytes"] for _, _, a in fin) == want
+    shared_cols = 4 * 2 * obs_store.rows_per_block * obs_store.n_blocks
+    assert max(a["d2h_bytes"] for _, _, a in fin) >= shared_cols
+    by_ticket = {a["ticket"]: a for _, _, a in fin}
+    for t in tickets:
+        a = by_ticket[t.ticket_id]
+        assert a["rows"] == t.result.n_rows
+        assert a["answer_bytes"] == t.result.n_rows * (1 + 1) * 4 == \
+            sum(v.nbytes for v in t.result.rows.values())
+
+
+def test_spans_reach_the_profiler_trace(obs_store, tmp_path):
+    """While a tracer is installed each span is also a profiler
+    annotation: the ``.xplane.pb`` holds as many as the obs events."""
+    import collections
+    import jax
+    server = js.HailServer(obs_store, js.ServerConfig(
+        max_batch=4, cluster=CLUSTER, result_cache=False))
+    for qq in EXPLAIN_QUERIES:
+        server.submit(qq)
+    server.flush()                     # compiles outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        tracer, _, _ = _traced_flush(server, EXPLAIN_QUERIES)
+    finally:
+        jax.profiler.stop_trace()
+    (xp,) = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(xp))
+    host = collections.Counter(
+        e.name for p in data.planes if p.name.startswith("/host")
+        for ln in p.lines for e in ln.events)
+    for name in ("dispatch", "wait", "finalize"):
+        n = len(_spans(tracer.events, name))
+        assert n > 0 and host[name] == n, name
 
 
 def test_trace_validator_rejects_malformed():
@@ -257,7 +397,7 @@ def test_trace_validator_rejects_malformed():
                  {"ph": "E", "pid": 1, "tid": 2, "name": "b", "ts": 4}]) == []
 
 
-def test_tracing_disabled_is_noop(obs_store):
+def test_tracing_disabled_is_noop(obs_store, monkeypatch):
     assert not obs_trace.enabled() and obs_trace.current() is None
     with obs_trace.span("x", track="t") as s:
         assert s is None                      # shared null context
@@ -265,13 +405,23 @@ def test_tracing_disabled_is_noop(obs_store):
     obs_trace.complete_wall("x", 0.0, 1.0)
     obs_trace.complete_sim("x", 0.0, 1.0)
     obs_trace.flow("s", 1, 0.0, track="t")
-    # a full (untraced) flush stays correct and emits no events anywhere
+    # a full (untraced) flush stays correct and records nothing: no tracer
+    # method runs, so neither an obs event nor a profiler annotation
+    idle = obs_trace.Tracer()
+    n0 = len(idle.events)
+
+    def boom(*a, **kw):
+        raise AssertionError("a tracer recorded an untraced flush")
+
+    for method in ("span", "instant", "complete_wall"):
+        monkeypatch.setattr(obs_trace.Tracer, method, boom)
     server = js.HailServer(obs_store, js.ServerConfig(max_batch=4,
                                                       cluster=CLUSTER))
     for qq in EXPLAIN_QUERIES:
         server.submit(qq)
     server.flush()
     assert all(t.status == "done" for t in server.tickets)
+    assert len(idle.events) == n0 and obs_trace.current() is None
 
 
 # ---------------------------------------------------------------------------
