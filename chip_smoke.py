@@ -172,7 +172,7 @@ def check_reader_program(rows: int):
         sds((1, rows // PARTITION), jnp.int32), sds((1, rows), jnp.int32),
         sds((1, 4, rows), jnp.int32), sds((1, rows), jnp.bool_),
         sds((1,), jnp.int32), sds((2, 2), jnp.int32),
-        partition_size=PARTITION,
+        reader=ops.hail_read_batch, partition_size=PARTITION,
         interpret=ops.interpret_mode()).compile().as_text()
     _expect("tpu_custom_call" in text, "no tpu_custom_call in the reader")
     print("reader program: tpu_custom_call present, interpret mode off "

@@ -479,6 +479,8 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
     touches only mask-true rows).  The second return value models the
     PHYSICAL I/O of the shared scan — per block, the widest partition range
     any query in the batch needed (a lazy 0-d array; no sync at dispatch).
+    The one program returns every one of these arrays already split per
+    query and column, so building the results launches no device program.
     """
     from repro.kernels import ops
 
@@ -493,7 +495,6 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
            else np.asarray(block_ids))
     rows = store.rows_per_block
     proj_cols = proj + (ROWID,)
-    col_bytes = 4 * rows
     if len(ids) == 0:
         return [_empty_read(store, proj_cols, rows) for _ in queries], 0
 
@@ -506,18 +507,13 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
             n_idx = int(uidx.astype(bool).sum())
             args.update(queries=len(queries), index_blocks=n_idx,
                         full_blocks=len(ids) - n_idx)
-        mask, out, frac = ops.hail_read_batch(
+        read = ops.hail_read_batch_split(
             mins, keys, proj_arr, bad, uidx, lohi,
             partition_size=store.partition_size)
-        cols = {c: out[:, j] for j, c in enumerate(proj_cols)}
-        results = [
-            ReadResult(cols=cols, mask=mask[:, qi],
-                       rows_read_frac=frac[:, qi],
-                       bytes_read=frac[:, qi].sum() * col_bytes
-                       * (1 + len(proj)))
-            for qi in range(len(queries))]
-        shared_bytes = frac.max(axis=1).sum() * col_bytes * (1 + len(proj))
-    return results, shared_bytes
+    cols = dict(zip(proj_cols, read.cols))
+    results = [ReadResult(cols=cols, mask=m, rows_read_frac=f, bytes_read=b)
+               for m, f, b in zip(read.masks, read.fracs, read.bytes_read)]
+    return results, read.shared_bytes
 
 
 def gather_shared_scan_inputs(store: BlockStore,

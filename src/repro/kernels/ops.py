@@ -21,6 +21,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,7 @@ from repro.core import checksum as _ck
 from repro.core import index as _idx
 from repro.kernels import interpret_default
 from repro.kernels.hail_reader import hail_read as _hail_read
-from repro.kernels.hail_reader import hail_read_batch as _hail_read_batch
+from repro.kernels.hail_reader import hail_read_batch
 from repro.obs import trace as _obs_trace
 
 _INTERPRET: bool | None = None       # None: follow the platform
@@ -131,13 +132,42 @@ def _hail_read_jit(mins, keys, proj, bad, use_index, lo, hi,
                       partition_size=partition_size, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("partition_size", "interpret"))
+class BatchRead(NamedTuple):
+    """One split of a shared scan, already split per column and query:
+    ``cols`` C projected columns (B, R) in their stored dtype, ``masks`` Q
+    (B, R) bool, ``fracs`` Q rows-read fractions (B,) f32, ``bytes_read`` Q
+    modeled bytes and ``shared_bytes`` the batch's physical bytes (per
+    block, the widest fraction), each a 0-d f32."""
+    cols: tuple
+    masks: tuple
+    fracs: tuple
+    bytes_read: tuple
+    shared_bytes: jax.Array
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("reader", "partition_size", "interpret"))
 def _hail_read_batch_jit(mins, keys, proj, bad, use_index, lohi,
-                         *, partition_size, interpret):
+                         *, reader, partition_size, interpret) -> BatchRead:
+    """``reader`` (static) and the split of its outputs as ONE program.
+
+    Modeled bytes are 4 B per row read for the key and for each of the
+    C - 1 projected attributes (the C-th column, the row id, is not read):
+    4 R C per block read whole.  A query's figure sums its fractions, the
+    shared one each block's widest."""
     TRACE_COUNTS["hail_read_batch"] += 1
-    return _hail_read_batch(mins, keys, proj, bad, use_index, lohi,
-                            partition_size=partition_size,
-                            interpret=interpret)
+    mask, out, frac = reader(mins, keys, proj, bad, use_index, lohi,
+                             partition_size=partition_size,
+                             interpret=interpret)
+    n_cols, rows = out.shape[1], out.shape[2]
+    col_bytes = 4 * rows
+    fracs = tuple(frac[:, qi] for qi in range(frac.shape[1]))
+    return BatchRead(
+        cols=tuple(out[:, j] for j in range(n_cols)),
+        masks=tuple(mask[:, qi] for qi in range(mask.shape[1])),
+        fracs=fracs,
+        bytes_read=tuple(f.sum() * col_bytes * n_cols for f in fracs),
+        shared_bytes=frac.max(axis=1).sum() * col_bytes * n_cols)
 
 
 @jax.jit
@@ -198,28 +228,37 @@ def hail_read(mins, keys, proj, bad, use_index, lo, hi, *,
                           interpret=interpret_mode())
 
 
-def hail_read_batch(mins, keys, proj, bad, use_index, lohi, *,
-                    partition_size: int):
-    """Fused shared-scan reader: ONE dispatch per (split, query-batch).
+def hail_read_batch_split(mins, keys, proj, bad, use_index, lohi, *,
+                          partition_size: int) -> BatchRead:
+    """Fused shared-scan read of one split: ONE dispatch per (split,
+    query-batch), returning each column's and each query's arrays
+    (``BatchRead``), so the caller launches no device program after it.
 
     ``lohi`` is the batch's (Q, 2) runtime lo/hi array; Q is a SHAPE, so a
     server batching at a fixed ``max_batch`` compiles one variant per
     distinct batch size (counted in ``traces``) and reuses it for every
-    later batch of that size.  The scan-mode counters charge each of the Q
+    later batch of that size.  ``use_index`` and ``lohi`` go to the program
+    as host arrays.  The scan-mode counters charge each of the Q
     queries with the blocks it logically scanned — serially-equivalent
     accounting, so adaptive/governor invariant tests see the same totals
     whether traffic was batched or not.  Per-column attribution stays the
-    record readers' job (``governor.attribute_read``, once per query)."""
+    record readers' job (``governor.attribute_read``, once per query).
+
+    The program wraps ``hail_read_batch`` (the kernel's traceable entry,
+    -> mask (B, Q, R), union-masked projection (B, C, R), fractions (B, Q))
+    as this module holds it at call time, keyed on that function: a reader
+    swapped in at run time (the benchmark's fault checks wrap it) compiles
+    its own program."""
     DISPATCH_COUNTS["hail_read"] += 1
     DISPATCH_COUNTS["hail_read_batch"] += 1
     lohi = np.asarray(lohi, np.int32).reshape(-1, 2)
     n_q = lohi.shape[0]
-    u = np.asarray(use_index)        # host array: counters cost no sync
-    n_idx = int(u.astype(bool).sum())
+    u = np.asarray(use_index, np.int32)  # host array: counters cost no sync
+    n_idx = int(np.count_nonzero(u))
     DISPATCH_COUNTS["index_scan_blocks"] += n_q * n_idx
     DISPATCH_COUNTS["full_scan_blocks"] += n_q * (u.shape[0] - n_idx)
-    return _hail_read_batch_jit(mins, keys, proj, bad,
-                                jnp.asarray(u, jnp.int32), jnp.asarray(lohi),
+    return _hail_read_batch_jit(mins, keys, proj, bad, u, lohi,
+                                reader=hail_read_batch,
                                 partition_size=partition_size,
                                 interpret=interpret_mode())
 
@@ -234,9 +273,9 @@ def _sharded_batch_reader(mesh, axes: tuple, partition_size: int,
 
     def local(mins, keys, proj, bad, use_index, lohi):
         TRACE_COUNTS["hail_read_sharded"] += 1
-        return _hail_read_batch(mins, keys, proj, bad, use_index, lohi,
-                                partition_size=partition_size,
-                                interpret=interpret)
+        return hail_read_batch(mins, keys, proj, bad, use_index, lohi,
+                               partition_size=partition_size,
+                               interpret=interpret)
 
     # block dim sharded over the scan axes; the (Q, 2) ranges replicated.
     # check_vma=False: outputs are per-shard block tiles, no replication

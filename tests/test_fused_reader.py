@@ -8,6 +8,7 @@ from repro.core import mapreduce as mr
 from repro.core import query as q
 from repro.core import schema as sc
 from repro.core import upload as up
+from repro.core.parse import format_rows
 from repro.kernels import ops
 
 Q1 = q.HailQuery(filter=("visitDate", 7305, 7670), projection=("sourceIP",))
@@ -205,6 +206,119 @@ def test_batch_reader_equals_serial_reads(hail_store):
     fracs = np.stack([np.asarray(r.rows_read_frac) for r in batch])
     assert float(shared) == pytest.approx(
         fracs.max(axis=0).sum() * 4 * hail_store.rows_per_block * 2)
+
+
+@pytest.fixture(scope="module")
+def synthetic_store():
+    """Four 1,024-row blocks of the 19-attribute Synthetic table, replicas
+    clustered on attr0..attr2."""
+    cols = sc.gen_synthetic(4 * 1024, seed=11)
+    raw = format_rows(sc.SYNTHETIC, cols, bad_fraction=0.002).reshape(
+        4, 1024, -1)
+    store, _ = up.hail_upload(sc.SYNTHETIC, raw, ["attr0", "attr1", "attr2"],
+                              partition_size=128, n_nodes=6)
+    return store
+
+
+def _syn_batch(store, n_q, n_proj, mixed):
+    """n_q queries on attr0 projecting n_proj attributes, and a plan whose
+    odd blocks (when ``mixed``) full-scan the replica clustered on attr1."""
+    rng = np.random.default_rng(100 * n_q + n_proj)
+    proj = tuple(f"attr{i}" for i in range(19))[-n_proj:]
+    queries = []
+    for _ in range(n_q):
+        lo = int(rng.integers(0, 2**20))
+        hi = lo + int(rng.choice([0, 3_000, 100_000, 2**20]))
+        queries.append(q.HailQuery(filter=("attr0", lo, hi),
+                                   projection=proj))
+    qp = q.plan(store, queries[0])
+    if mixed:
+        qp.replica_for_block[1::2] = store.replica_by_key("attr1")
+        qp.index_scan[1::2] = False
+    return queries, qp
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["index", "mixed"])
+@pytest.mark.parametrize("n_proj", [1, 2, 19])
+@pytest.mark.parametrize("n_q", [1, 3, 8])
+def test_batch_reader_split_in_program_equals_eager(synthetic_store, n_q,
+                                                    n_proj, mixed):
+    """``read_hail_batch``'s per-query masks, fractions and bytes, its
+    columns and its shared bytes — split inside the reader's program —
+    equal the same split made eagerly from one ``ops.hail_read_batch``
+    call, which itself equals the jnp oracle: values, shapes and dtypes."""
+    import jax.numpy as jnp
+    from repro.kernels import ref
+
+    store = synthetic_store
+    queries, qp = _syn_batch(store, n_q, n_proj, mixed)
+    proj_cols = queries[0].projection + (q.ROWID,)
+    ids = np.arange(store.n_blocks)
+    lohi = np.asarray([qq.filter[1:] for qq in queries], np.int32)
+    mins, keys, proj, bad, uidx = q._gather_split_inputs(
+        store, qp, ids, "attr0", proj_cols)
+    assert set(np.asarray(uidx).tolist()) == ({0, 1} if mixed else {1})
+    mask, out, frac = ops.hail_read_batch(mins, keys, proj, bad, uidx, lohi,
+                                          partition_size=128,
+                                          interpret=ops.interpret_mode())
+    want = ref.hail_read_batch(mins, keys, proj, bad, jnp.asarray(uidx),
+                               jnp.asarray(lohi), partition_size=128)
+    for got, exp in zip((mask, out, frac), want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+    batch, shared = q.read_hail_batch(store, queries, qp)
+    col_bytes = 4 * store.rows_per_block * len(proj_cols)
+    assert len(batch) == n_q
+    for qi, res in enumerate(batch):
+        eager = {"mask": mask[:, qi], "rows_read_frac": frac[:, qi],
+                 "bytes_read": frac[:, qi].sum() * col_bytes}
+        for name, exp in eager.items():
+            got = getattr(res, name)
+            assert (got.shape, got.dtype) == (exp.shape, exp.dtype), name
+            np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                       rtol=np.finfo(np.float32).eps)
+        np.testing.assert_array_equal(np.asarray(res.mask),
+                                      np.asarray(mask[:, qi]))
+        assert list(res.cols) == list(proj_cols)
+        for j, c in enumerate(proj_cols):
+            exp = out[:, j]
+            assert (res.cols[c].shape, res.cols[c].dtype) == \
+                (exp.shape, exp.dtype)
+            np.testing.assert_array_equal(np.asarray(res.cols[c]),
+                                          np.asarray(exp))
+    exp = frac.max(axis=1).sum() * col_bytes
+    assert (shared.shape, shared.dtype) == (exp.shape, exp.dtype)
+    np.testing.assert_allclose(float(shared), float(exp),
+                               rtol=np.finfo(np.float32).eps)
+
+
+def test_batch_read_is_one_program(synthetic_store):
+    """Reading a split is ONE device program: with a batch width no other
+    test uses, ``read_hail_batch`` compiles the reader and nothing else —
+    no program slices its outputs — and new ranges compile nothing."""
+    from bench.stats import compile_counter
+
+    def compiles():
+        return compile_counter().snapshot()["compiles"]
+
+    store = synthetic_store
+    queries, qp = _syn_batch(store, 7, 5, mixed=True)
+    # the gather's own programs (concatenate, take) compile here, once
+    q.read_hail_batch(store, queries[:1], qp)
+    before = compiles()
+    with ops.stats_scope() as s:
+        res, shared = q.read_hail_batch(store, queries, qp)
+        float(shared)
+        assert compiles() - before == 1
+        assert s.dispatches["hail_read_batch"] == 1
+        moved = [q.HailQuery(filter=("attr0", qq.filter[1] // 2,
+                                     qq.filter[2] // 2),
+                             projection=qq.projection) for qq in queries]
+        res, shared = q.read_hail_batch(store, moved, qp)
+        float(shared)
+    assert compiles() - before == 1
+    assert s.dispatches["hail_read_batch"] == 2
+    assert len(res) == 7 and len(res[0].cols) == 6
 
 
 def test_run_job_pipelines_splits(hail_store):

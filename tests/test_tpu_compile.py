@@ -3,6 +3,7 @@
 Each test compiles (``interpret=False``, through Mosaic) for a described
 ``v5e:2x2`` topology at deployment shapes — 2^19-row blocks, 1024-row
 partitions — without a chip attached: the fused reader for Q = 1 and 8,
+the served program that also splits its outputs per column and query,
 the block sort behind adaptive builds and repair, and the shard_map'd
 reader over a four-chip mesh.  Nothing runs; a passing compile is not a
 chip run.  The topology is described inside a fixture, never at import.
@@ -62,6 +63,28 @@ def test_fused_reader_compiles_for_v5e(topo, c, n_q):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 2 * c * ROWS * 4
+
+
+@pytest.mark.parametrize("n_q", [1, 8])
+def test_split_reader_compiles_for_v5e(topo, n_q):
+    """The served reader's one program, which returns each of the 20
+    projected columns and each query's mask, fraction and bytes."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    c = 20
+    with ops.stats_scope(merge=False):
+        lowered = ops._hail_read_batch_jit.lower(
+            *_reader_args(one_chip, 2, c, n_q), reader=ops.hail_read_batch,
+            partition_size=PART, interpret=False)
+    out = lowered.out_info
+    assert [a.shape for a in out.cols] == [(2, ROWS)] * c
+    assert [a.dtype for a in out.masks] == [jnp.bool_] * n_q
+    assert [a.shape for a in out.fracs] == [(2,)] * n_q
+    assert [a.shape for a in out.bytes_read] == [()] * n_q
+    assert out.shared_bytes.shape == ()
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 2 * (4 * c + n_q) * ROWS
 
 
 def test_sort_block_compiles_for_v5e(topo):
