@@ -168,6 +168,13 @@ class ReadResult:
     # device sync — run_job materializes it at the completion barrier
 
 
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _tail_mask(n_bad, *, rows: int):
+    """(blocks, rows) bool: the last ``n_bad[b]`` rows of each block."""
+    return (jnp.arange(rows, dtype=jnp.int32)[None, :]
+            >= rows - n_bad[:, None])
+
+
 def _bad_mask(store: BlockStore, replica: int) -> jax.Array:
     """Bad rows sit at the tail of INDEXED blocks (sorted there); for a
     block that is still unindexed they stay at their original upload
@@ -178,13 +185,20 @@ def _bad_mask(store: BlockStore, replica: int) -> jax.Array:
     if replica in cache:
         return cache[replica]
     rep = store.replicas[replica]
+    rows = store.rows_per_block
+    if store.devices:
+        # placed: every replica is indexed; each chip makes its own blocks'
+        m = rep.mins.map_parts(lambda part, blocks: _tail_mask(
+            jax.device_put(store.bad_counts[blocks], part.sharding),
+            rows=rows))
+        cache[replica] = m
+        return m
     orig = (store.bad_original if store.bad_original is not None
-            else jnp.zeros((store.n_blocks, store.rows_per_block), bool))
+            else jnp.zeros((store.n_blocks, rows), bool))
     if rep.sort_key is None:
         m = orig
     else:
-        r = jnp.arange(store.rows_per_block, dtype=jnp.int32)[None, :]
-        tail = r >= (store.rows_per_block - store.bad_counts[:, None])
+        tail = _tail_mask(store.bad_counts, rows=rows)
         if rep.indexed.all():
             m = tail
         else:
@@ -506,7 +520,8 @@ def read_hail_batch(store: BlockStore, queries: Sequence[HailQuery],
         if args is not None:
             n_idx = int(uidx.astype(bool).sum())
             args.update(queries=len(queries), index_blocks=n_idx,
-                        full_blocks=len(ids) - n_idx)
+                        full_blocks=len(ids) - n_idx,
+                        chip=store.chip_of(qplan.nodes[ids[0]]))
         read = ops.hail_read_batch_split(
             mins, keys, proj_arr, bad, uidx, lohi,
             partition_size=store.partition_size)

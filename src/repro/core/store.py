@@ -121,6 +121,52 @@ class Namenode:
             self.dead.discard(node)
 
 
+@dataclasses.dataclass(frozen=True)
+class PlacedBlocks:
+    """One replica's per-block array of a placed store, held as one array per
+    chip: block b is row ``slot[b]`` of ``parts[chip[b]]``.
+
+    Indexing reads like the (n_blocks, ...) array it stands for, on the
+    leading (block) index, with further indices applied to each block.  The
+    blocks read must all lie on one chip, and the result is that chip's
+    array: a read across chips raises, so nothing built from a placed
+    replica spans chips."""
+    parts: tuple                   # per chip: (blocks held there, ...)
+    chip: np.ndarray               # (n_blocks,) chip of each block
+    slot: np.ndarray               # (n_blocks,) row of each block there
+
+    @property
+    def dtype(self):
+        return self.parts[0].dtype
+
+    @property
+    def size(self) -> int:
+        return sum(int(p.size) for p in self.parts)
+
+    def blocks_on(self, chip: int) -> np.ndarray:
+        """Block ids held on ``chip``, in slot order."""
+        mine = np.flatnonzero(self.chip == chip)
+        return mine[np.argsort(self.slot[mine])]
+
+    def map_parts(self, fn) -> "PlacedBlocks":
+        """A placed array laid out like this one: ``fn(part, blocks)`` makes
+        each chip's part from this one's and the block ids it holds."""
+        return PlacedBlocks(
+            tuple(fn(p, self.blocks_on(k)) for k, p in enumerate(self.parts)),
+            self.chip, self.slot)
+
+    def __getitem__(self, key):
+        blocks, rest = (key[0], key[1:]) if isinstance(key, tuple) else (key,
+                                                                         ())
+        if isinstance(blocks, slice):
+            blocks = np.arange(len(self.chip))[blocks]
+        chips = np.unique(self.chip[blocks])
+        if len(chips) != 1:
+            raise ValueError(f"blocks {blocks} lie on chips {chips.tolist()}: "
+                             f"a read of a placed replica stays on one chip")
+        return self.parts[int(chips[0])][(self.slot[blocks],) + rest]
+
+
 @dataclasses.dataclass
 class Replica:
     """One sort order of the whole dataset: per-column (n_blocks, rows).
@@ -131,7 +177,8 @@ class Replica:
     order; an indexed block's rows are sorted by ``sort_key`` with bad
     records at the tail.  ``sort_key is None`` with all-False ``indexed``
     means the replica is still unclaimed — the first adaptive commit claims
-    it for the workload's filter column.
+    it for the workload's filter column.  In a placed store (``BlockStore.
+    devices``) ``cols``, ``mins`` and ``checksums`` hold ``PlacedBlocks``.
     """
     sort_key: Optional[str]
     cols: dict[str, jax.Array]
@@ -199,6 +246,25 @@ class BlockStore:
     version: int = 0                       # bumped by every destructive
     #   transition; part of the result-cache key, so answers filled against
     #   an older store state are structurally unreachable
+    devices: tuple = ()                    # the chips of a PLACED store:
+    #   replica r's block b lives on devices[chip_of(nodes[r, b])], its
+    #   arrays are PlacedBlocks and bad_counts is a host array; empty: one
+    #   chip holds every replica, as plain (n_blocks, ...) arrays
+
+    @property
+    def n_chips(self) -> int:
+        return max(1, len(self.devices))
+
+    def chip_of(self, node: int) -> int:
+        """The chip of a datanode: nodes go round the chips, so one chip
+        holds every node."""
+        return int(node) % self.n_chips
+
+    def _single_chip(self, what: str):
+        if self.devices:
+            raise NotImplementedError(
+                f"{what} rewrites replica blocks in place, which a store "
+                f"placed over {len(self.devices)} chips does not support")
 
     def _note_destructive(self):
         """Every state transition that changes what a query would read
@@ -334,6 +400,7 @@ class BlockStore:
         import time as _time
         from repro.kernels import ops
         assert self.layout == "pax", "repair targets PAX replicas"
+        self._single_chip("repair_blocks")
         t0 = _time.perf_counter()
         stats = RepairStats()
         by_rep: dict[int, list[int]] = {}
@@ -445,6 +512,7 @@ class BlockStore:
         BEFORE building, so a trim here means someone committed directly).
         Returns the number of blocks actually committed.
         """
+        self._single_chip("commit_block_indexes")
         rep = self.replicas[replica_id]
         assert rep.sort_key in (None, sort_key), \
             f"replica {replica_id} already keyed on {rep.sort_key!r}"
@@ -502,6 +570,7 @@ class BlockStore:
         dropped (budget blocks freed).
         """
         assert self.layout == "pax", "only PAX replicas carry indexes"
+        self._single_chip("demote_replica")
         rep = self.replicas[replica_id]
         assert rep.sort_key is not None, \
             f"replica {replica_id} is already unindexed"
@@ -576,6 +645,7 @@ class BlockStore:
         """
         from repro.kernels import ops
         assert self.layout == "pax", "dynamic replication targets PAX stores"
+        self._single_chip("add_replica")
         live = self.live_replica_ids()
         if n_nodes is None:
             n_nodes = max(int(self.replicas[i].nodes.max())

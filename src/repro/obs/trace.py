@@ -4,9 +4,11 @@ trace-event JSON (Perfetto-loadable).
 Two clocks, two trace processes:
 
 * **pid 1 "hail (measured wall)"** — real ``time.perf_counter`` sections:
-  upload phases, flush lifecycle (result-cache probe, batching, plan,
-  per-split dispatch — prune, gather, issue — then per-split wait and
-  per-ticket finalize; verify and cache fill), adaptive builds,
+  upload phases (a placed upload's ``upload_parse``, ``upload_ship`` and
+  ``upload_index``, each with its chip), flush lifecycle (result-cache
+  probe, batching, plan, per-split dispatch — prune, gather, issue — then
+  per-split wait and per-ticket finalize, a split's ``dispatch``,
+  ``issue`` and ``wait`` with its chip; verify and cache fill), adaptive builds,
   demotions, quarantine/repair instants, scrubber ticks.
 * **pid 2 "cluster (simulated)"** — the deterministic simulated timeline:
   ``run_schedule`` task runs become per-node tracks, ``ServerFrontend``

@@ -74,6 +74,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
 from typing import Optional, Sequence
 
@@ -119,7 +120,9 @@ class ServerConfig:
     over — splits gather host-side as usual but dispatch in WAVES of up to
     n_dev splits through one shard_map'd fused call (see
     ``mapreduce.run_job``); meshes without a multi-device scan axis fall
-    back to the serial per-split dispatch.
+    back to the serial per-split dispatch.  A store placed over chips
+    (``BlockStore.devices``) ignores it: each split is read on the chip
+    that holds its blocks, and the splits are issued round the chips.
     """
     max_batch: int = 8
     max_pending_per_tenant: int = 8
@@ -214,6 +217,8 @@ class FlushStats:
     blocks_quarantined: int = 0    # corrupt (replica, block)s this flush found
     corrupt_retries: int = 0       # batch splits re-planned after corruption
     scrub_s: float = 0.0           # boundary scrub wall (verify + repair)
+    chip_blocks: dict = dataclasses.field(default_factory=dict)
+    # ^ chip -> blocks of the splits dispatched there (live splits only)
 
 
 def flush_tasks(stats: FlushStats) -> list[Task]:
@@ -564,6 +569,7 @@ class HailServer:
             qplan = q.plan(store, query0)
         splits = (hail_splits(store, qplan, self.config.cluster.map_slots)
                   if store.layout == "pax" else hadoop_splits(store, qplan))
+        splits = _across_chips(splits, store)
         fail_after = (int(len(splits) * fail["frac"])
                       if fail["frac"] is not None and fail["node"] is None
                       else None)
@@ -583,14 +589,14 @@ class HailServer:
                     store.unindexed_blocks(adapt_rid)):
                 adapt_rid = None             # already converged
 
-        dispatched = []               # (results, shared_bytes, t, live qis)
+        dispatched = []     # (results, shared_bytes, t, live qis, chip)
 
         # sharded scan: buffer up to n_dev gathered splits per wave and
         # dispatch the wave as ONE shard_map'd fused call (mapreduce.run_job
         # has the serial-equivalence argument: gathered inputs are
         # snapshots, so buffering cannot change any split's row-set)
         use_sharded = (self.config.mesh is not None
-                       and store.layout == "pax"
+                       and store.layout == "pax" and not store.devices
                        and query0.filter is not None)
         scan_axes: tuple = ()
         n_dev = 1
@@ -612,7 +618,7 @@ class HailServer:
                                                 self.config.mesh, scan_axes)
             for (live_qis, _), (res, shared) in zip(wave, out):
                 dispatched.append((res, shared, time.perf_counter(),
-                                   live_qis))
+                                   live_qis, 0))
             wave.clear()
 
         pending = list(splits)
@@ -631,11 +637,12 @@ class HailServer:
                         break
                 sp = pending[i]
                 i += 1
+                chip = store.chip_of(sp.node)
                 with obs_trace.span("dispatch", track="server") as args:
                     with obs_trace.span("prune", track="server"):
                         live = self._live_members(qplan, sp, queries)
                     if args is not None:
-                        args.update(split=i - 1,
+                        args.update(split=i - 1, chip=chip,
                                     blocks=[int(b) for b in sp.block_ids],
                                     live=[batch[qi].ticket_id for qi in live])
                     if not live:
@@ -669,7 +676,9 @@ class HailServer:
                         wave.append((tuple(live), gathered))
                     else:
                         dispatched.append((res, shared, time.perf_counter(),
-                                           tuple(live)))
+                                           tuple(live), chip))
+                    stats.chip_blocks[chip] = (stats.chip_blocks.get(chip, 0)
+                                               + len(sp.block_ids))
                     d_wall, demote_pending = demote_pending, 0.0
                     b_wall = 0.0
                     if adapt_rid is not None and budget["left"] > 0:
@@ -758,16 +767,17 @@ class HailServer:
                         answer_bytes=sum(v.nbytes for v in rows.values()))
 
         remaining = [0] * len(queries)     # live splits still outstanding
-        for _, _, _, live in dispatched:
+        for _, _, _, live, _ in dispatched:
             for qi in live:
                 remaining[qi] += 1
         for qi in range(len(queries)):
             if remaining[qi] == 0:
                 finalize(qi)               # live on nothing: done at once
-        for res, shared, t_disp, live in dispatched:
+        for res, shared, t_disp, live, chip in dispatched:
             with obs_trace.span("wait", track="server") as args:
                 if args is not None:
-                    args["live"] = [batch[qi].ticket_id for qi in live]
+                    args.update(live=[batch[qi].ticket_id for qi in live],
+                                chip=chip)
                 jax.block_until_ready(res[0].mask)
                 split_wall = time.perf_counter() - t_disp
                 stats.bytes_read += int(shared)
@@ -777,6 +787,20 @@ class HailServer:
                 remaining[qi] -= 1
                 if remaining[qi] == 0:
                     finalize(qi)
+
+
+def _across_chips(splits: list, store: BlockStore) -> list:
+    """The splits in an order that goes round the store's chips (each
+    chip's own splits in their order), so that every chip has work queued
+    before the host waits on any; on one chip the order is unchanged."""
+    by_chip: dict = {}
+    for sp in splits:
+        by_chip.setdefault(store.chip_of(sp.node), []).append(sp)
+    if len(by_chip) <= 1:
+        return splits
+    queues = [by_chip[k] for k in sorted(by_chip)]
+    return [sp for rnd in itertools.zip_longest(*queues) for sp in rnd
+            if sp is not None]
 
 
 def _first_copy_bytes(parts, names, copied: set) -> int:

@@ -107,3 +107,26 @@ def test_sharded_reader_compiles_for_four_chips(topo):
     mem = compiled.memory_analysis()
     # each chip holds its own block tile: one block of every operand
     assert mem.argument_size_in_bytes < 2 * (4 + 4 * 4 + 1) * ROWS
+
+
+def test_placed_upload_pass_compiles_for_v5e(topo):
+    """One pass of the placed upload on a chip: the client's parse of
+    ``PASS_BLOCKS`` home blocks and a datanode's sort, root and checksums
+    of as many blocks of one replica.  Their temporaries stay a small part
+    of the chip beside the 3 GB of PAX and 2.3 GB of text it holds."""
+    from repro.core import schema as sc
+    from repro.core import upload as up
+    one_chip = SingleDeviceSharding(topo.devices[1])
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    b, width = up.PASS_BLOCKS, 91
+    parse = up._parse_pipeline(sc.USERVISITS).lower(
+        sds((b, ROWS, width), jnp.uint8), sds((b,), jnp.int32)).compile()
+    cols = {c: sds((b, ROWS), jnp.int32)
+            for c in sc.USERVISITS.names + ("__rowid__",)}
+    index = up._index_pipeline(PART).lower(
+        cols, sds((b, ROWS), jnp.bool_), cols["visitDate"]).compile()
+    assert "sort" in index.as_text()
+    for compiled in (parse, index):
+        mem = compiled.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < 3e9, mem
