@@ -13,9 +13,10 @@ used by the scheduler to route map tasks to matching indexes (§3.3, §4.3).
 Adaptive indexing (LIAH, the paper's sequel) makes the store STATE-EVOLVING:
 blocks may upload unindexed (``Replica.indexed`` all-False) and running jobs
 commit per-block clustered indexes back via ``commit_block_indexes`` — the
-replica's columns, root directory, checksums, per-block index flags and the
-namenode's Dir_rep all advance together, and query-side caches (the bad-row
-mask, any attached ``core/cache.BlockCache``) are invalidated.  Planning reads this LIVE state, so repeated jobs
+replica's columns, root directory, host key ranges, checksums, per-block
+index flags and the namenode's Dir_rep all advance together, and query-side
+caches (the bad-row mask, any attached ``core/cache.BlockCache``) are
+invalidated.  Planning reads this LIVE state, so repeated jobs
 converge from all-full-scan to all-index-scan.
 
 The index governor (core/governor.py) adds the REVERSE transition:
@@ -167,6 +168,40 @@ class PlacedBlocks:
         return self.parts[int(chips[0])][(self.slot[blocks],) + rest]
 
 
+@jax.jit
+def _key_range_cols(mins, keys, n_bad):
+    last = jnp.maximum(keys.shape[1] - 1 - n_bad, 0)
+    return mins[:, 0], jnp.take_along_axis(keys, last[:, None], axis=1)[:, 0]
+
+
+def block_key_ranges(mins, keys, n_bad) -> np.ndarray:
+    """(k, 2) int64 host key ranges of k indexed blocks, from their root
+    directories ``mins`` (k, parts), sorted key column ``keys`` (k, rows)
+    and host bad-record counts ``n_bad`` (k,): one small program on the
+    blocks' chip and one device-to-host read.  An all-bad block's row is
+    meaningless (it has no good key)."""
+    lo, hi = jax.device_get(_key_range_cols(mins, keys,
+                                            np.asarray(n_bad, np.int32)))
+    return np.stack([np.asarray(lo).astype(np.int64),
+                     np.asarray(hi).astype(np.int64)], axis=1)
+
+
+def replica_key_ranges(rep: "Replica", bad_counts: np.ndarray) -> np.ndarray:
+    """The key ranges of every block of an indexed replica; a placed
+    replica is read per chip, from that chip's part, with nothing moved
+    between chips."""
+    keys = rep.cols[rep.sort_key]
+    if not isinstance(rep.mins, PlacedBlocks):
+        return block_key_ranges(rep.mins, keys, bad_counts)
+    out = np.zeros((len(rep.mins.chip), 2), np.int64)
+    for k, part in enumerate(rep.mins.parts):
+        blocks = rep.mins.blocks_on(k)
+        if len(blocks):
+            out[blocks] = block_key_ranges(part, keys.parts[k],
+                                           bad_counts[blocks])
+    return out
+
+
 @dataclasses.dataclass
 class Replica:
     """One sort order of the whole dataset: per-column (n_blocks, rows).
@@ -190,11 +225,18 @@ class Replica:
     #   slot stays (replica ids are baked into caches, the AccessLog and
     #   recorded plans) but planning, repair, scrubbing and byte accounting
     #   all skip it; its columns are dropped
+    key_range: Optional[np.ndarray] = None  # (n_blocks, 2) int64 HOST copy
+    #   of each block's root minimum ``mins[b, 0]`` and last good sorted key
+    #   ``cols[sort_key][b, rows - bad[b] - 1]``, meaningful where
+    #   ``indexed[b]``; set by the store's own writes (upload, commit,
+    #   repair), never read back from the device, so pruning costs no sync
 
     def __post_init__(self):
         if self.indexed is None:
             self.indexed = np.full(len(self.nodes),
                                    self.sort_key is not None, dtype=bool)
+        if self.key_range is None:
+            self.key_range = np.zeros((len(self.nodes), 2), np.int64)
 
     def block_indexed(self, block_id: int) -> bool:
         return self.sort_key is not None and bool(self.indexed[block_id])
@@ -222,7 +264,8 @@ class BlockStore:
     rows_per_block: int
     partition_size: int
     replicas: list[Replica]
-    bad_counts: jax.Array                  # (n_blocks,) bad records per block
+    bad_counts: np.ndarray                 # (n_blocks,) bad records per
+    #   block, on the host (pruning reads it per split)
     namenode: Namenode
     layout: str = "pax"
     bad_original: Optional[jax.Array] = None  # (n_blocks, rows) upload order
@@ -247,9 +290,9 @@ class BlockStore:
     #   transition; part of the result-cache key, so answers filled against
     #   an older store state are structurally unreachable
     devices: tuple = ()                    # the chips of a PLACED store:
-    #   replica r's block b lives on devices[chip_of(nodes[r, b])], its
-    #   arrays are PlacedBlocks and bad_counts is a host array; empty: one
-    #   chip holds every replica, as plain (n_blocks, ...) arrays
+    #   replica r's block b lives on devices[chip_of(nodes[r, b])] and its
+    #   arrays are PlacedBlocks; empty: one chip holds every replica, as
+    #   plain (n_blocks, ...) arrays
 
     @property
     def n_chips(self) -> int:
@@ -389,8 +432,8 @@ class BlockStore:
            ``sort_key`` with bad records to the tail (the stable device
            sort reproduces a fresh eager upload's layout bit-for-bit) and
            rebuild the root-directory row;
-        3. splice columns + root + freshly recomputed checksums, clear the
-           quarantine, and invalidate the bad-mask/block caches for just
+        3. splice columns + root + freshly recomputed checksums (and the
+           block's host key range), clear the quarantine, and invalidate the bad-mask/block caches for just
            the touched blocks.
 
         The governor's AccessLog is untouched — repair restores bytes, it
@@ -429,8 +472,12 @@ class BlockStore:
                     keys = jnp.where(self.bad_original[b][None], big,
                                      upload_cols[rep.sort_key])
                     _, new_cols, _ = ops.sort_block(keys, upload_cols)
-                    rep.mins = rep.mins.at[b].set(idx.build_block_roots(
-                        new_cols[rep.sort_key], self.partition_size)[0])
+                    roots = idx.build_block_roots(new_cols[rep.sort_key],
+                                                  self.partition_size)
+                    rep.mins = rep.mins.at[b].set(roots[0])
+                    rep.key_range[b] = block_key_ranges(
+                        roots, new_cols[rep.sort_key],
+                        self.bad_counts[b:b + 1])[0]
                 else:
                     new_cols = upload_cols
                     rep.mins = rep.mins.at[b].set(jnp.int32(0))
@@ -503,8 +550,8 @@ class BlockStore:
 
         Splices the sorted columns, per-block root directories and recomputed
         checksums into the replica (functional ``.at`` updates — reads already
-        dispatched against the old arrays are unaffected), flips the blocks'
-        ``indexed`` flags, advances the namenode's Dir_rep, and invalidates
+        dispatched against the old arrays are unaffected), sets the blocks'
+        host key ranges from them, flips the blocks' ``indexed`` flags, advances the namenode's Dir_rep, and invalidates
         the per-replica bad-row-mask cache (tail layout changed).
 
         When a governor is attached, the commit is trimmed to the budget's
@@ -541,6 +588,8 @@ class BlockStore:
         for c, v in sorted_cols.items():
             rep.cols[c] = rep.cols[c].at[bsel].set(v)
         rep.mins = idx.merge_block_roots(rep.mins, bsel, new_mins)
+        rep.key_range[bsel] = block_key_ranges(
+            new_mins, sorted_cols[sort_key], self.bad_counts[bsel])
         for c, s in new_checksums.items():
             rep.checksums[c] = rep.checksums[c].at[bsel].set(s)
         rep.indexed[bsel] = True
@@ -560,7 +609,7 @@ class BlockStore:
 
         The replica's rows return to upload order by sorting on the logical
         ``__rowid__`` column (identity for blocks that were never indexed),
-        the root directory zeroes, per-replica checksums are recomputed for
+        the root directory and host key ranges zero, per-replica checksums are recomputed for
         the restored byte order, ``sort_key``/``indexed`` rewind to the
         unclaimed state, the namenode's Dir_rep rewinds per block, and the
         bad-row-mask cache invalidates (bad rows move from the sorted tail
@@ -608,6 +657,7 @@ class BlockStore:
             jnp.int32)
         rep.sort_key = None
         rep.indexed = np.zeros(self.n_blocks, dtype=bool)
+        rep.key_range = np.zeros((self.n_blocks, 2), np.int64)
         for b in range(self.n_blocks):
             self.namenode.update_index(b, int(rep.nodes[b]), None)
         self.__dict__.get("_bad_mask_cache", {}).pop(replica_id, None)
@@ -739,6 +789,7 @@ class BlockStore:
         rep.cols = {}
         rep.checksums = {}
         rep.mins = None
+        rep.key_range = None
         self.__dict__.get("_bad_mask_cache", {}).pop(replica_id, None)
         if self.block_cache is not None:
             self.block_cache.invalidate_replica(replica_id)

@@ -33,7 +33,7 @@ from repro.core import index as idx
 from repro.core import parse as ps
 from repro.core.schema import ROWID, Schema
 from repro.core.store import (BlockStore, Namenode, PlacedBlocks, Replica,
-                              ReplicaInfo, assign_nodes)
+                              ReplicaInfo, assign_nodes, replica_key_ranges)
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -181,7 +181,7 @@ def hail_upload(schema: Schema, raw_blocks: np.ndarray,
                    jnp.arange(n_blocks, dtype=jnp.int32))
     jax.block_until_ready(reps)
     wall = time.perf_counter() - t0
-    bad_counts = bad.sum(axis=1).astype(jnp.int32)
+    bad_counts = np.asarray(bad.sum(axis=1), np.int32)
 
     nodes = assign_nodes(n_blocks, len(sort_keys), n_nodes)
     namenode = Namenode()
@@ -190,6 +190,8 @@ def hail_upload(schema: Schema, raw_blocks: np.ndarray,
     for r, (cols, mins, sums) in enumerate(reps):
         rep = Replica(sort_key=sort_keys[r], cols=cols, mins=mins,
                       checksums=sums, nodes=nodes[r])
+        if rep.sort_key is not None:
+            rep.key_range = replica_key_ranges(rep, bad_counts)
         replicas.append(rep)
         written += rep.nbytes
         per_block_bytes = rep.nbytes // n_blocks
@@ -328,6 +330,7 @@ def placed_upload(schema: Schema, raw_blocks, sort_keys: tuple,
                       checksums={c: placed(r, lambda pc, c=c: pc[2][c])
                                  for c in names},
                       nodes=nodes[r])
+        rep.key_range = replica_key_ranges(rep, bad_counts)
         for k in range(n_chips):
             pieces.pop((r, k), None)
         replicas.append(rep)
@@ -364,7 +367,8 @@ def hail_lazy_upload(schema: Schema, raw_blocks: np.ndarray,
     sort nor the index build — that work is earned back incrementally by
     ``run_job(adaptive=...)`` piggybacking on full-scan map tasks.  Replicas
     start unclaimed (``sort_key=None``, ``indexed`` all-False) with zeroed
-    root directories sized for ``partition_size``.
+    root directories sized for ``partition_size``; their host key ranges
+    fill as ``commit_block_indexes`` lands.
     """
     n_blocks, rows, width = raw_blocks.shape
     fn = _lazy_pipeline(schema)
@@ -373,7 +377,7 @@ def hail_lazy_upload(schema: Schema, raw_blocks: np.ndarray,
                          jnp.arange(n_blocks, dtype=jnp.int32))
     jax.block_until_ready(bad)
     wall = time.perf_counter() - t0
-    bad_counts = bad.sum(axis=1).astype(jnp.int32)
+    bad_counts = np.asarray(bad.sum(axis=1), np.int32)
 
     nodes = assign_nodes(n_blocks, replication, n_nodes)
     namenode = Namenode()
@@ -434,7 +438,7 @@ def hdfs_upload(schema: Schema, raw_blocks: np.ndarray, replication: int = 3,
                 nbytes=rows * width))
     store = BlockStore(schema=schema, n_blocks=n_blocks, rows_per_block=rows,
                        partition_size=0, replicas=replicas,
-                       bad_counts=jnp.zeros((n_blocks,), jnp.int32),
+                       bad_counts=np.zeros((n_blocks,), np.int32),
                        namenode=namenode, layout="row_ascii")
     stats = UploadStats(wall_s=wall, ascii_bytes=raw_blocks.size,
                         written_bytes=raw_blocks.size * replication,

@@ -504,30 +504,32 @@ class HailServer:
         block's good rows span exactly [root-directory min, last good sorted
         key] — bad records sort to the tail — so a query range that misses
         that span on every block of the split contributes zero rows and the
-        member need not wait on (or even dispatch) it."""
+        member need not wait on (or even dispatch) it.  The span is the
+        replica's host ``key_range``, set by the store where it writes the
+        block (upload, commit, repair), and the bad counts are host too: no
+        device read.  Runs in the ``prune`` span, whose arguments give the
+        blocks whose range was compared and whether the split is dead."""
         store = self.store
-        if store.layout != "pax" or queries[0].filter is None:
-            return list(range(len(queries)))
-        if any(not qplan.index_scan[b] for b in sp.block_ids):
-            return list(range(len(queries)))
-        col = queries[0].filter_col
-        rows = store.rows_per_block
-        bad = np.asarray(store.bad_counts)
-        live: set[int] = set()
-        for b in sp.block_ids:
-            rep = store.replicas[int(qplan.replica_for_block[b])]
-            n_good = rows - int(bad[b])
-            if n_good <= 0:
-                continue                     # every row bad: nothing to read
-            kmin = int(np.asarray(rep.mins[b, 0]))
-            kmax = int(np.asarray(rep.cols[col][b, n_good - 1]))
-            for qi, qq in enumerate(queries):
-                _, lo, hi = qq.filter
-                if hi >= kmin and lo <= kmax:
-                    live.add(qi)
-            if len(live) == len(queries):
-                break
-        return sorted(live)
+        with obs_trace.span("prune", track="server") as args:
+            live = np.ones(len(queries), dtype=bool)
+            checked = 0
+            if (store.layout == "pax" and queries[0].filter is not None
+                    and all(qplan.index_scan[b] for b in sp.block_ids)):
+                lo = np.array([qq.filter[1] for qq in queries])
+                hi = np.array([qq.filter[2] for qq in queries])
+                live[:] = False
+                for b in sp.block_ids:
+                    if store.bad_counts[b] >= store.rows_per_block:
+                        continue             # every row bad: nothing to read
+                    rep = store.replicas[int(qplan.replica_for_block[b])]
+                    kmin, kmax = rep.key_range[b]
+                    checked += 1
+                    live |= (hi >= kmin) & (lo <= kmax)
+                    if live.all():
+                        break
+            if args is not None:
+                args.update(blocks_checked=checked, dead=int(not live.any()))
+            return np.flatnonzero(live).tolist()
 
     def _empty_col(self, c: str) -> np.ndarray:
         """Zero-row column in the STORED dtype (a plan can yield zero live
@@ -639,8 +641,7 @@ class HailServer:
                 i += 1
                 chip = store.chip_of(sp.node)
                 with obs_trace.span("dispatch", track="server") as args:
-                    with obs_trace.span("prune", track="server"):
-                        live = self._live_members(qplan, sp, queries)
+                    live = self._live_members(qplan, sp, queries)
                     if args is not None:
                         args.update(split=i - 1, chip=chip,
                                     blocks=[int(b) for b in sp.block_ids],

@@ -150,6 +150,23 @@ def bit_equal_upload(ctx):
 
 
 @check
+def key_ranges(ctx):
+    """Each replica's host key range table, read per chip at upload, is
+    every block's min and max good key, as on the one-device store."""
+    cols, bad = ctx["cols"], ctx["bad"]
+    for store in (ctx["placed"], ctx["placed_passes"]):
+        for r, rep in enumerate(store.replicas):
+            assert rep.key_range.shape == (BLOCKS, 2)
+            for b in range(BLOCKS):
+                rows = slice(b * ROWS, (b + 1) * ROWS)
+                good = cols[rep.sort_key][rows][~bad[rows]]
+                assert tuple(rep.key_range[b]) == (good.min(), good.max())
+            assert np.array_equal(rep.key_range,
+                                  ctx["one"].replicas[r].key_range)
+    return {}
+
+
+@check
 def answers(ctx):
     """HailServer's answers from the placed store equal the oracle's and
     the one-device store's (range, point, bad rows, 1 and 3 columns)."""

@@ -1,8 +1,9 @@
 """A HAIL store placed over four chips (four virtual CPU devices, in the
 subprocess ``placed_worker.py``): each replica's blocks live on the chip of
 their datanode, the placed upload is bit-equal to the one-device upload,
-HailServer's answers equal a numpy oracle and the one-device store's, every
-reader program stays on its split's chip, and one chip is today's store."""
+each replica's host key ranges equal a numpy oracle, HailServer's answers
+equal the oracle and the one-device store's, every reader program stays on
+its split's chip, and one chip is today's store."""
 import json
 import os
 import pathlib
@@ -12,7 +13,7 @@ import sys
 import pytest
 
 WORKER = pathlib.Path(__file__).with_name("placed_worker.py")
-CHECKS = ["placement", "bit_equal_upload", "answers",
+CHECKS = ["placement", "bit_equal_upload", "key_ranges", "answers",
           "reads_stay_on_their_chip", "failover_reads_other_chips",
           "one_chip_is_todays_store",
           "placed_store_refuses_rewrites_and_cross_chip_reads"]
